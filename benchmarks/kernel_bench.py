@@ -13,6 +13,12 @@ from repro.kernels.ops import (
 )
 
 
+def _pool(rng, n_pages, page, KV, hd):
+    """Random pages in the pool's head-major layout (n_pages, KV, page, hd)."""
+    return jnp.asarray(rng.standard_normal((n_pages, KV, page, hd)),
+                       jnp.float32)
+
+
 def main(csv: Csv | None = None):
     csv = csv or Csv()
     rng = np.random.default_rng(0)
@@ -32,8 +38,8 @@ def main(csv: Csv | None = None):
 
     n_pages, page, ppseq = 64, 16, 16
     q2 = jnp.asarray(rng.standard_normal((4, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)), jnp.float32)
+    kp = _pool(rng, n_pages, page, KV, hd)
+    vp = _pool(rng, n_pages, page, KV, hd)
     tbl = jnp.asarray(rng.integers(0, n_pages, (4, ppseq)), jnp.int32)
     lens = jnp.full((4,), page * ppseq, jnp.int32)
     _, us = timed(lambda: paged_decode_attention_op(
@@ -50,10 +56,8 @@ def main(csv: Csv | None = None):
     for psize in (8, 16, 32):
         pps = 256 // psize
         nps = 4 * pps + 4          # room for 4 sequences' disjoint tables
-        kp2 = jnp.asarray(
-            rng.standard_normal((nps, psize, KV, hd)), jnp.float32)
-        vp2 = jnp.asarray(
-            rng.standard_normal((nps, psize, KV, hd)), jnp.float32)
+        kp2 = _pool(rng, nps, psize, KV, hd)
+        vp2 = _pool(rng, nps, psize, KV, hd)
         tbl2 = jnp.asarray(
             rng.permutation(nps)[:4 * pps].reshape(4, pps), jnp.int32)
         lens2 = jnp.full((4,), 256, jnp.int32)
@@ -70,16 +74,16 @@ def main(csv: Csv | None = None):
     nps = 4 * pps + 2
     Tq2, ctx = 64, 128
     qp3 = jnp.asarray(rng.standard_normal((4, Tq2, H, hd)), jnp.float32)
-    kp3 = jnp.asarray(rng.standard_normal((nps, psize, KV, hd)), jnp.float32)
-    vp3 = jnp.asarray(rng.standard_normal((nps, psize, KV, hd)), jnp.float32)
+    kp3 = _pool(rng, nps, psize, KV, hd)
+    vp3 = _pool(rng, nps, psize, KV, hd)
     tbl3 = jnp.asarray(rng.integers(0, nps, (4, pps)), jnp.int32)
     off3 = jnp.full((4,), ctx, jnp.int32)
     _, us = timed(lambda: paged_prefill_attention_op(
         qp3, kp3, vp3, tbl3, off3, bq=32, bk=64,
         interpret=True).block_until_ready())
     _, us_ref = timed(lambda: chunked_prefill_attention_ref(
-        qp3, gather_pages(kp3, tbl3), gather_pages(vp3, tbl3),
-        off3).block_until_ready())
+        qp3, gather_pages(kp3, tbl3).swapaxes(1, 2),
+        gather_pages(vp3, tbl3).swapaxes(1, 2), off3).block_until_ready())
     flops = 4 * 4 * Tq2 * (ctx + Tq2) * H * hd
     v5e = flops / 197e12 * 1e6
     csv.add("kernel/paged_prefill", us,

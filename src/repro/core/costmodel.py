@@ -38,8 +38,29 @@ class HardwareSpec:
 
 A100 = HardwareSpec("A100-80G", peak_flops=312e12, hbm_bw=2.039e12,
                     link_bw=100e9)       # 4x200 Gbps ConnectX-6 RoCE
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s per chip
 TPU_V5E = HardwareSpec("TPU-v5e", peak_flops=197e12, hbm_bw=819e9,
                        link_bw=50e9)
+
+#: The one hardware table, keyed by ``jax.Device.device_kind``.  The CPU
+#: backend runs the tests, which price it as the paper's A100 so engine
+#: and simulator sessions make identical scheduling decisions.
+HARDWARE_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": A100,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The spec of a device kind in :data:`HARDWARE_BY_DEVICE_KIND`; an
+    unknown kind is an error, never a default."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.costmodel import A100, BatchCostModel, HardwareSpec
+from repro.core.costmodel import BatchCostModel, HardwareSpec, hardware_for
 from repro.core.precision import get_precision
 from repro.core.request import Request
 from repro.core.session import (
@@ -135,7 +135,7 @@ class EngineBackend(Backend):
     emits_tokens = True
 
     def __init__(self, cfg: ModelConfig, params, n_slots: int = 8,
-                 max_len: int = 512, hw: HardwareSpec = A100,
+                 max_len: int = 512, hw: Optional[HardwareSpec] = None,
                  transfer_chunk: int = 32, seed: int = 0,
                  kv_mode: str = "auto", page_size: int = 8,
                  n_pages: Optional[int] = None,
@@ -160,6 +160,9 @@ class EngineBackend(Backend):
         self.n_pages = (n_pages if n_pages is not None
                         else n_slots * pages_for(max_len, page_size)) \
             if self.paged else None
+        if hw is None:
+            import jax
+            hw = hardware_for(jax.devices()[0].device_kind)
         self.cost = BatchCostModel(cfg, hw)
         self.engines: Dict[int, InstanceEngine] = {}
         self.records: Dict[str, _ReqRecord] = {}
@@ -175,6 +178,7 @@ class EngineBackend(Backend):
         self.devices_per_instance = devices_per_instance
         self.hw = hw
         self._costs: Dict[int, BatchCostModel] = {1: self.cost}
+        self._params_on: Dict[object, object] = {}   # device -> params
         self.handoff_bytes_saved = 0
         self.handoff_saved_by_iid: Dict[int, int] = {}
         self._rng = np.random.default_rng(seed)
@@ -220,13 +224,14 @@ class EngineBackend(Backend):
         return self._costs[n]
 
     def _instance_devices(self, iid: int):
-        """Deterministic round-robin sub-mesh for instance ``iid`` (on
-        forced-host CPU the devices are virtual, so overlap is fine —
-        assignment only has to be reproducible)."""
+        """Deterministic round-robin devices for instance ``iid``: a
+        one-device instance gets device ``iid % n_devices`` (so replicas
+        and their alpha→beta handoffs spread over a host's chips), a
+        sharded one a sub-mesh of consecutive devices (on forced-host
+        CPU the devices are virtual, so overlap is fine — assignment
+        only has to be reproducible)."""
         import jax
         n = self.devices_for(iid)
-        if n <= 1:
-            return None
         all_devs = jax.devices()
         if n > len(all_devs):
             raise ValueError(
@@ -244,15 +249,28 @@ class EngineBackend(Backend):
             self.handoff_saved_by_iid.get(iid, 0) + int(nbytes)
 
     # ---------------- pool lifecycle ----------------
+    def _params_for(self, devices):
+        """The weights a one-device instance runs on, placed once per
+        device and shared by every instance there (a sharded instance
+        places its own shards)."""
+        if len(devices) > 1:
+            return self.params
+        import jax
+        dev = devices[0]
+        if dev not in self._params_on:
+            self._params_on[dev] = jax.device_put(self.params, dev)
+        return self._params_on[dev]
+
     def spawn(self, iid: int) -> None:
         if iid not in self.engines:
+            devices = self._instance_devices(iid)
             eng = InstanceEngine(
-                self.cfg, self.params, self.n_slots, self.max_len,
-                kv_mode=self.kv_mode,
+                self.cfg, self._params_for(devices), self.n_slots,
+                self.max_len, kv_mode=self.kv_mode,
                 page_size=self.page_size or 8, n_pages=self.n_pages,
                 max_chunk=self.max_chunk, prefix_cache=self.prefix_cache,
                 kv_precision=self._precision_for(iid).name,
-                devices=self._instance_devices(iid))
+                devices=devices)
             # the engine owns the auto-mode rule; the backend's page
             # bookkeeping (register/admission/total_pages) must agree
             assert eng.paged == self.paged, \

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
-from repro.core.costmodel import A100, HardwareSpec
+from repro.core.costmodel import HardwareSpec
 from repro.core.request import SLOClass
 from repro.core.session import (
     ServeHandle, ServeSession, SessionConfig, SessionStallError,
@@ -48,7 +48,7 @@ class ServingCluster:
     def __init__(self, cfg: ModelConfig, params, n_instances: int = 2,
                  n_slots: int = 8, max_len: int = 512,
                  prefill_budget: int = 64, transfer_chunk: int = 32,
-                 split: bool = True, hw: HardwareSpec = A100,
+                 split: bool = True, hw: Optional[HardwareSpec] = None,
                  slo: float = 0.100, admission: bool = False,
                  default_slo: Optional[SLOClass] = None,
                  prefix_cache: bool = False,

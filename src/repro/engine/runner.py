@@ -20,12 +20,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map_compat
 from repro.core.precision import get_precision
 from repro.engine.block_allocator import (
     BlockAllocator, CapacityError, OutOfPages, pages_for,
 )
 from repro.engine.prefix_cache import PrefixCache
+from repro.kernels.ops import from_pool_layout, to_pool_layout
 from repro.models.config import ModelConfig
 from repro.models.model import (
     forward, init_cache, init_paged_cache, supports_paged_kv,
@@ -80,8 +80,7 @@ class StepHandle:
     logits: object              # device array, possibly still computing
 
     def ready(self) -> bool:
-        from repro.compat import array_is_ready
-        return array_is_ready(self.logits)
+        return self.logits.is_ready()
 
 
 class InstanceEngine:
@@ -98,7 +97,8 @@ class InstanceEngine:
       for ring-buffer / recurrent / enc-dec architectures.
     * ``"auto"`` (default) — paged when the architecture supports it.
 
-    ``devices`` makes the instance *sharded*: a list of n devices forms a
+    ``devices`` places the instance: one device pins its params and KV
+    pool there; a list of n > 1 devices makes it *sharded*, a
     1-D ``("model",)`` sub-mesh and every step runs as one jitted
     ``shard_map`` over it — tensor-parallel attention/MLP (heads / ffn
     sharded, psum at the output projections) and expert-parallel MoE
@@ -141,12 +141,16 @@ class InstanceEngine:
             raise ValueError("quantized KV formats live on the page pool; "
                              f"kv_precision={self.kv_precision.name!r} "
                              f"requires a paged KV mode")
+        # a one-device instance allocates its pool on its own device
+        device = self.devices[0] if self.tp == 1 and self.devices else None
         if self.paged:
             self.page_size = page_size
             self.n_pages = (n_pages if n_pages is not None
                             else n_slots * pages_for(max_len, page_size))
-            self.cache = init_paged_cache(cfg, self.n_pages, page_size,
-                                          kv_precision=self.kv_precision)
+            with jax.default_device(device):
+                self.cache = init_paged_cache(
+                    cfg, self.n_pages, page_size,
+                    kv_precision=self.kv_precision)
             self.allocator = BlockAllocator(self.n_pages, page_size, n_slots,
                                             precision=self.kv_precision)
             self.page_buckets = bucket_ladder(self.n_pages)
@@ -157,8 +161,9 @@ class InstanceEngine:
             self.page_size = None
             self.n_pages = None
             self.allocator = None
-            self.cache = init_cache(cfg, n_slots, max_len,
-                                    window_override=window_override)
+            with jax.default_device(device):
+                self.cache = init_cache(cfg, n_slots, max_len,
+                                        window_override=window_override)
         # shared-prefix KV cache: trie over the page pool + per-slot
         # claims; the allocator evicts through it under pressure
         self.prefix: Optional[PrefixCache] = None
@@ -172,6 +177,9 @@ class InstanceEngine:
         self._cache_specs = None
         if self.tp > 1:
             self._shard_instance()
+        elif device is not None:
+            self.params = jax.device_put(params, device)
+            self.cache = jax.device_put(self.cache, device)
         self.free_slots = list(range(n_slots))
         self.slot_owner: Dict[int, str] = {}
         self._step_fns: Dict[tuple, callable] = {}
@@ -380,8 +388,9 @@ class InstanceEngine:
 
         in_specs = (self._param_specs, self._cache_specs) + \
             (P(),) * n_batch_args
-        return shard_map_compat(body, self.mesh, in_specs,
-                                (P(), self._cache_specs))
+        return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=(P(), self._cache_specs),
+                             check_vma=False)
 
     # ---------------- execution ----------------
     def run_batch(self, items: Sequence[BatchItem]) -> Dict[int, np.ndarray]:
@@ -615,15 +624,19 @@ class InstanceEngine:
                      "precision": self.kv_precision.name}
             for i in range(len(self.cfg.layer_pattern)):
                 c = self.cache["blocks"][i]
-                pc = {
-                    "k": np.asarray(c["k_pages"][:, ids]),
-                    "v": np.asarray(c["v_pages"][:, ids]),
-                }
-                if "k_scales" in c:
-                    # quantized pool: the per-token-row dequant scales
-                    # ride with their code pages
-                    pc["k_scales"] = np.asarray(c["k_scales"][:, ids])
-                    pc["v_scales"] = np.asarray(c["v_scales"][:, ids])
+                # the wire format is token-major (G, n, page, KV, hd) +
+                # (G, n, page) scales, whatever the pool's own layout
+                pc = {}
+                for kv in ("k", "v"):
+                    scales = c.get(f"{kv}_scales")
+                    codes, sc = from_pool_layout(
+                        np.asarray(c[f"{kv}_pages"][:, ids]),
+                        None if scales is None else np.asarray(scales[:, ids]))
+                    pc[kv] = codes
+                    if sc is not None:
+                        # quantized pool: the per-token dequant scales
+                        # ride with their code pages
+                        pc[f"{kv}_scales"] = sc
                 piece["pages"].append(pc)
             yield piece
             if p1 >= n_need:
@@ -687,22 +700,21 @@ class InstanceEngine:
             return
         ids = np.concatenate(all_ids)
         blocks = list(self.cache["blocks"])
+
+        def cat(parts):
+            return jnp.concatenate([jnp.asarray(a) for a in parts], axis=1)
+
         for i in range(len(blocks)):
-            nb = {
-                "k_pages": blocks[i]["k_pages"].at[:, ids].set(
-                    jnp.concatenate([jnp.asarray(a) for a in per_k[i]],
-                                    axis=1)),
-                "v_pages": blocks[i]["v_pages"].at[:, ids].set(
-                    jnp.concatenate([jnp.asarray(a) for a in per_v[i]],
-                                    axis=1)),
-            }
-            if quantized:
-                nb["k_scales"] = blocks[i]["k_scales"].at[:, ids].set(
-                    jnp.concatenate([jnp.asarray(a) for a in per_ks[i]],
-                                    axis=1))
-                nb["v_scales"] = blocks[i]["v_scales"].at[:, ids].set(
-                    jnp.concatenate([jnp.asarray(a) for a in per_vs[i]],
-                                    axis=1))
+            nb = {}
+            for kv, codes, scales in (("k", per_k[i], per_ks[i]),
+                                      ("v", per_v[i], per_vs[i])):
+                pages, sc = to_pool_layout(
+                    cat(codes), cat(scales) if quantized else None)
+                nb[f"{kv}_pages"] = blocks[i][f"{kv}_pages"].at[:, ids].set(
+                    pages)
+                if quantized:
+                    nb[f"{kv}_scales"] = \
+                        blocks[i][f"{kv}_scales"].at[:, ids].set(sc)
             blocks[i] = nb
         self.cache = dict(self.cache, blocks=tuple(blocks))
 

@@ -10,7 +10,12 @@ the vLLM paged-attention pattern adapted to TPU:
   * grid = (B, KV, n_pages_per_seq), pages innermost-sequential with
     online-softmax scratch carried across page steps;
   * all q heads of one KV group (q_per_kv rows) are processed together so
-    the MXU tile is (q_per_kv, hd) x (hd, page).
+    the MXU tile is (q_per_kv, hd) x (hd, page);
+  * the pool is head-major inside a page, (n_pages, KV, page, hd), so a
+    streamed tile is (page, hd): the last two block dimensions are the
+    (sublane, lane) tile Mosaic requires.  A token-major page would put
+    a block of 1 (one KV head) in the second-minor dimension, which the
+    TPU compiler refuses.
 """
 from __future__ import annotations
 
@@ -21,8 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -50,23 +53,24 @@ def _kernel(tables_ref, lens_ref,          # scalar prefetch
     @pl.when(ip * page < length)
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)             # (qpk, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (page, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            # in-register dequant: one f32 scale per token row of the
-            # page, prefetched alongside the page tile
-            k = k * ks_ref[0, :][:, None]
-            v = v * vs_ref[0, :][:, None]
+        k = k_ref[0, 0, :, :].astype(jnp.float32)             # (page, hd)
+        v = v_ref[0, 0, :, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if quantized:
+            # in-register dequant: one f32 scale per token of the page,
+            # a lane vector (1, page).  Scaling K's rows scales the score
+            # columns, and scaling V's rows scales the columns of p.
+            s = s * ks_ref[0]
         s = jnp.where(pos < length, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
+        pv = p * vs_ref[0] if quantized else p
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -79,16 +83,16 @@ def _kernel(tables_ref, lens_ref,          # scalar prefetch
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                            k_scales=None, v_scales=None, *,
                            interpret: bool = False):
-    """q: (B,H,hd); k/v_pages: (n_pages,page,KV,hd);
+    """q: (B,H,hd); k/v_pages: (n_pages,KV,page,hd);
     block_tables: (B,n_pp) int32; lengths: (B,) -> (B,H,hd).
 
-    ``k_scales``/``v_scales``: optional (n_pages, page) f32 per-token-row
+    ``k_scales``/``v_scales``: optional (n_pages, 1, page) f32 per-token
     dequant scales for quantized (fp8/int8) page pools — prefetched by
     the same block-table index_map as the pages and applied in-register
-    after the f32 cast.
+    to the f32 scores and probabilities.
     """
     B, H, hd = q.shape
-    n_pages, page, KV, _ = k_pages.shape
+    n_pages, KV, page, _ = k_pages.shape
     n_pp = block_tables.shape[1]
     qpk = H // KV
     qg = q.reshape(B, KV, qpk, hd)
@@ -103,18 +107,18 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         pl.BlockSpec((1, 1, qpk, hd),
                      lambda b, h, ip, tbl, ln: (b, h, 0, 0)),
         # physical page chosen from the prefetched block table
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, hd),
-                     lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, h, 0)),
+        pl.BlockSpec((1, 1, page, hd),
+                     lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
+        pl.BlockSpec((1, 1, page, hd),
+                     lambda b, h, ip, tbl, ln: (tbl[b, ip], h, 0, 0)),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, page),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, h, ip, tbl, ln: (tbl[b, ip], 0, 0)),
         ]
         operands += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
@@ -134,7 +138,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, qpk, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, *operands)

@@ -42,19 +42,19 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     """Paged GQA decode attention (one query token per sequence).
 
     q:            (B, H, hd)
-    k_pages:      (n_pages, page, KV, hd)
-    v_pages:      (n_pages, page, KV, hd)
+    k_pages:      (n_pages, KV, page, hd) — head-major inside each page
+    v_pages:      (n_pages, KV, page, hd)
     block_tables: (B, pages_per_seq) int32 — physical page per logical page
     lengths:      (B,) int32 — valid context per sequence (incl. current tok)
     Returns       (B, H, hd).
     """
     B, H, hd = q.shape
-    n_pages, page, KV, _ = k_pages.shape
+    n_pages, KV, page, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     S = pages_per_seq * page
-    # gather logical KV per sequence
-    k = k_pages[block_tables].reshape(B, S, KV, hd)
-    v = v_pages[block_tables].reshape(B, S, KV, hd)
+    # gather logical KV per sequence: (B, n_pp, KV, page, hd) -> (B, S, KV, hd)
+    k = jnp.swapaxes(k_pages[block_tables], 2, 3).reshape(B, S, KV, hd)
+    v = jnp.swapaxes(v_pages[block_tables], 2, 3).reshape(B, S, KV, hd)
     qpk = H // KV
     qg = q.reshape(B, KV, qpk, hd)
     scores = jnp.einsum("bkgh,bskh->bkgs", qg, k).astype(jnp.float32)

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, ASSIGNED_ARCHS, INPUT_SHAPES, canonical, get_config
-from repro.compat import cost_analysis_dict, make_mesh_compat
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (
     batch_shardings, cache_shardings, effective_window, input_specs,
@@ -96,7 +95,8 @@ def _mesh_from_env(multi_pod: bool):
     if spec:
         dims = tuple(int(x) for x in spec.split("x"))
         axes = ("pod", "data", "model") if len(dims) == 3 else ("data", "model")
-        return make_mesh_compat(dims, axes)
+        return jax.make_mesh(dims, axes, axis_types=(
+            jax.sharding.AxisType.Auto,) * len(axes))
     return make_production_mesh(multi_pod=multi_pod)
 
 
@@ -254,7 +254,7 @@ def extract_costs(cfg, shape, mesh) -> dict:
         with mesh:
             compiled = jax.jit(step, in_shardings=in_sh,
                                donate_argnums=donate).lower(*args).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_stats(compiled.as_text())
         ms.append({
             "flops": float(cost.get("flops", 0.0)),

@@ -6,18 +6,20 @@ import; tests and benches must keep seeing 1 device).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh_compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU tests (requires >= n_data*n_model host devices)."""
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

@@ -10,6 +10,10 @@ SLO-attaining tokens per second measured at the API.
   # real JAX engines, wall clock, open-loop arrivals (the CI smoke job)
   PYTHONPATH=src python -m repro.launch.serve --smoke --open-loop
 
+  # real engines at Qwen2.5-14B's published widths, cut to 8 layers
+  PYTHONPATH=src python -m repro.launch.serve --backend engine \\
+      --layers 8 --prompt-len 1024 --max-new 64 --open-loop
+
   # simulator, paper workloads, elastic pool, admission control
   PYTHONPATH=src python -m repro.launch.serve --backend sim \\
       --workload burstgpt --qps 3 --duration 30 --policy elastic --admission
@@ -18,8 +22,9 @@ from __future__ import annotations
 
 import argparse
 import os
+import pathlib
 import sys
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -76,17 +81,55 @@ def _ensure_host_devices(n: int) -> None:
             flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads it itself), otherwise
+    at the fixed path ``<checkout>/.jax_cache``: the path is part of the
+    cache key, so a moving directory would never hit.  Returns the
+    directory in use."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = pathlib.Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir",
+                          str(root / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+def engine_model(arch: str, layers: Optional[int], seed: int = 0):
+    """``(cfg, params)`` for the engine backend, weights random from
+    ``seed``.  ``layers=None`` is the arch's reduced CPU smoke config;
+    an int keeps every published width and cuts the depth to that many
+    layers."""
+    import jax
+    from repro.configs import get_config, get_smoke_config
+    from repro.models.model import init_params
+
+    if layers is None:
+        cfg = get_smoke_config(arch)
+    else:
+        cfg = get_config(arch)
+        if not 0 < layers <= cfg.n_layers or layers % cfg.pattern_len:
+            raise ValueError(
+                f"{arch}: cannot cut {cfg.n_layers} layers (pattern of "
+                f"{cfg.pattern_len}) to {layers}")
+        cfg = cfg.with_(n_layers=layers)
+    # one jitted program: no eager per-tensor f32 temporaries on device
+    params = jax.jit(init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed))
+    return cfg, params
+
+
 def mini_trace(n: int, qps: float, seed: int,
                slo_mix: Optional[Dict[str, float]],
                p_max: int = 48, d_max: int = 16) -> List[Request]:
-    """Engine-scale trace: tiny prompts/outputs that fit a reduced
-    model's cache, Poisson arrivals, SLO classes by mix."""
+    """Engine-scale trace: Poisson arrivals, SLO classes by mix, prompt
+    and output lengths uniform in ``[max // 4, max)``."""
     rng = np.random.default_rng(seed)
     reqs, t = [], 0.0
     for i in range(n):
         t += rng.exponential(1.0 / qps)
-        p = int(rng.integers(8, p_max))
-        d = int(rng.integers(4, d_max))
+        p = int(rng.integers(max(1, p_max // 4), p_max))
+        d = int(rng.integers(max(1, d_max // 4), d_max))
         reqs.append(Request(f"online-{i}", t, p, d, predicted_decode=d,
                             slo=pick_slo(rng, slo_mix)))
     return reqs
@@ -160,17 +203,24 @@ def _finish_recorder(rec, args) -> None:
                   f"{c.tbt_misses:>8} {c.top_cause or '-':>20}")
 
 
-def serve_engine(args) -> SessionMetrics:
+def engine_layers(args) -> Optional[int]:
+    """The engine's depth: ``None`` (the smoke config) under --smoke,
+    else --layers, defaulting to the published depth."""
+    if args.smoke:
+        return None
+    from repro.configs import get_config
+    return args.layers or get_config(args.arch).n_layers
+
+
+def serve_engine(args, model=None) -> Tuple[SessionMetrics, ServeSession]:
+    """Serve ``mini_trace`` on real engines; ``model`` is an
+    ``engine_model`` result to reuse (built from ``args`` if None)."""
     dpi = parse_devices(args.devices_per_instance)
-    _ensure_host_devices(args.instances * _max_width(dpi))
-    import jax
-    from repro.configs import get_smoke_config
     from repro.engine.backend import EngineBackend
-    from repro.models.model import init_params
     from repro.sim.policies import DynaServePolicy
 
-    cfg = get_smoke_config(args.arch)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    cfg, params = model or engine_model(args.arch, engine_layers(args),
+                                        args.seed)
     mix = parse_slo_mix(args.slo_mix)
     reqs = mini_trace(args.requests, args.qps, args.seed, mix,
                       p_max=args.prompt_len, d_max=args.max_new)
@@ -205,7 +255,7 @@ def serve_engine(args) -> SessionMetrics:
     if not args.admission and m.completed != m.offered:
         raise SystemExit(f"smoke failure: {m.offered - m.completed} "
                          f"request(s) did not complete")
-    return m
+    return m, session
 
 
 def serve_sim(args) -> SessionMetrics:
@@ -261,15 +311,12 @@ def serve_sim(args) -> SessionMetrics:
 
 def serve_http(args) -> None:
     """Long-lived front door: OpenAI-compatible HTTP + /metrics."""
-    if (args.backend or "sim") == "engine":
-        _ensure_host_devices(
-            args.instances * _max_width(parse_devices(
-                args.devices_per_instance)))
     from repro.serving.http import ServerConfig, ServingServer
 
     cfg = ServerConfig(
         host=args.host, port=args.port,
         backend=args.backend or "sim", arch=args.arch,
+        layers=engine_layers(args),
         n_instances=args.instances, slo=args.slo,
         admission=args.admission, overlap=args.overlap or None,
         prefix_cache=args.prefix_cache, page_size=args.page_size,
@@ -291,7 +338,7 @@ def serve_http(args) -> None:
     server.serve_forever()
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--backend", choices=["sim", "engine"], default=None,
                     help="default: engine with --smoke, sim otherwise")
@@ -317,6 +364,10 @@ def main(argv=None):
                          "memory for --perfetto/--attribution)")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced model + tiny trace (CI-sized)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="engine backend without --smoke: serve the "
+                         "published widths of --arch cut to this many "
+                         "layers (default: every layer)")
     ap.add_argument("--open-loop", action="store_true",
                     help="honor arrival timestamps on the wall clock "
                          "(engine backend; the simulator is always "
@@ -368,12 +419,20 @@ def main(argv=None):
     ap.add_argument("--qps", type=float, default=2.0)
     ap.add_argument("--duration", type=float, default=30.0)
     ap.add_argument("--policy", choices=["dyna", "elastic"], default="dyna")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    backend = args.backend or ("engine" if args.smoke and not args.http
+                               else "sim")
+    if backend == "engine":
+        _ensure_host_devices(args.instances * _max_width(
+            parse_devices(args.devices_per_instance)))
+    use_compile_cache()
     if args.http:
         serve_http(args)
         return 0
-    backend = args.backend or ("engine" if args.smoke else "sim")
     if backend == "engine":
         serve_engine(args)
     else:

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map_compat as _shard_map_compat
 from repro.models.config import ModelConfig
 from repro.models.layers import (
     ParamFactory, apply_rope, init_norm, norm_fwd, rms_head_norm, rope_tables,
@@ -276,7 +275,8 @@ def _flash_decode_seqsharded(cfg: ModelConfig, q, k, v, qpos, kpos,
                 P(d_axes, None, None, None),       # extra k (in-flight)
                 P(d_axes, None, None, None),       # extra v
                 P(d_axes, None))                   # extra pos
-    sm = _shard_map_compat(body, mesh, in_specs, P(d_axes, None, None))
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(d_axes, None, None), check_vma=False)
     ek, ev, epos = extra if extra is not None else (None, None, None)
     if ek is None:
         ek = jnp.zeros((B, 1, KV, hd), k.dtype)
@@ -302,8 +302,7 @@ def _paged_attention_fwd(p, q, k, v, cfg: ModelConfig, cache, batch_pos,
     Returns (y_pre_wo, new_cache).
     """
     from repro.kernels.ops import (
-        gather_pages, gather_scales, chunked_prefill_attention_op,
-        paged_decode_attention_op, quantize_kv,
+        paged_decode_attention_op, paged_prefill_attention_op, quantize_kv,
     )
     B, T = batch_pos.shape
     n_pages = cache["k_pages"].shape[0]
@@ -329,16 +328,18 @@ def _paged_attention_fwd(p, q, k, v, cfg: ModelConfig, cache, batch_pos,
         prec = "int8" if cache["k_pages"].dtype == jnp.int8 else "fp8"
         kq, ksc = quantize_kv(k, prec)                    # (B,T,KV,hd),(B,T)
         vq, vsc = quantize_kv(v, prec)
-        ck = cache["k_pages"].at[phys, within].set(kq, mode="drop")
-        cv = cache["v_pages"].at[phys, within].set(vq, mode="drop")
-        cks = cache["k_scales"].at[phys, within].set(ksc, mode="drop")
-        cvs = cache["v_scales"].at[phys, within].set(vsc, mode="drop")
+        # pools are (n_pages, KV, page, hd): the (phys, :, within) index
+        # takes a (B, T, KV, hd) update; scale planes are (n_pages, 1, page)
+        ck = cache["k_pages"].at[phys, :, within].set(kq, mode="drop")
+        cv = cache["v_pages"].at[phys, :, within].set(vq, mode="drop")
+        cks = cache["k_scales"].at[phys, 0, within].set(ksc, mode="drop")
+        cvs = cache["v_scales"].at[phys, 0, within].set(vsc, mode="drop")
         new_cache = {"k_pages": ck, "v_pages": cv,
                      "k_scales": cks, "v_scales": cvs}
     else:
-        ck = cache["k_pages"].at[phys, within].set(
+        ck = cache["k_pages"].at[phys, :, within].set(
             k.astype(cache["k_pages"].dtype), mode="drop")
-        cv = cache["v_pages"].at[phys, within].set(
+        cv = cache["v_pages"].at[phys, :, within].set(
             v.astype(cache["v_pages"].dtype), mode="drop")
         cks = cvs = None
         new_cache = {"k_pages": ck, "v_pages": cv}
@@ -347,12 +348,8 @@ def _paged_attention_fwd(p, q, k, v, cfg: ModelConfig, cache, batch_pos,
         y = paged_decode_attention_op(q[:, 0], ck, cv, block_tables, lengths,
                                       cks, cvs)
         return y.reshape(B, 1, -1), new_cache
-    offsets = batch_pos[:, 0]
-    kg = gather_pages(ck, block_tables)
-    vg = gather_pages(cv, block_tables)
-    ksg = None if cks is None else gather_scales(cks, block_tables)
-    vsg = None if cvs is None else gather_scales(cvs, block_tables)
-    y = chunked_prefill_attention_op(q, kg, vg, offsets, ksg, vsg)
+    y = paged_prefill_attention_op(q, ck, cv, block_tables, batch_pos[:, 0],
+                                   cks, cvs)
     return y.reshape(B, T, -1), new_cache
 
 

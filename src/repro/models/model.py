@@ -222,10 +222,15 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     batch.  No ``pos`` array either — a paged position is its logical
     index by construction.
 
+    Pages are head-major, ``(G, n_pages, KV, page_size, hd)``: the
+    kernels stream one ``(page_size, hd)`` tile per KV head, the tiling
+    Mosaic accepts (``repro.kernels.ops`` converts to and from the
+    token-major handoff form).
+
     ``kv_precision`` (name or ``PagePrecision``, default bf16) selects
     the page storage format: a quantized pool stores fp8/int8 codes in
-    ``k_pages``/``v_pages`` plus per-token-row f32 dequant scales in
-    ``k_scales``/``v_scales`` of shape (G, n_pages, page_size) — the
+    ``k_pages``/``v_pages`` plus per-token f32 dequant scales in
+    ``k_scales``/``v_scales`` of shape (G, n_pages, 1, page_size) — the
     scale planes the paged kernels prefetch by the same block table."""
     from repro.core.precision import get_precision
     from repro.kernels.ops import kv_storage_dtype
@@ -245,14 +250,14 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     caches = []
     for _ in cfg.layer_pattern:
         c = {
-            "k_pages": make((G, n_pages, page_size, cfg.n_kv_heads, cfg.hd),
+            "k_pages": make((G, n_pages, cfg.n_kv_heads, page_size, cfg.hd),
                             dt),
-            "v_pages": make((G, n_pages, page_size, cfg.n_kv_heads, cfg.hd),
+            "v_pages": make((G, n_pages, cfg.n_kv_heads, page_size, cfg.hd),
                             dt),
         }
         if prec.quantized:
-            c["k_scales"] = make((G, n_pages, page_size), jnp.float32)
-            c["v_scales"] = make((G, n_pages, page_size), jnp.float32)
+            c["k_scales"] = make((G, n_pages, 1, page_size), jnp.float32)
+            c["v_scales"] = make((G, n_pages, 1, page_size), jnp.float32)
         caches.append(c)
     return {"blocks": tuple(caches)}
 

@@ -101,18 +101,25 @@ class SessionDriver:
             self._thread.join(timeout=timeout)
             self._thread = None
 
+    def new_rid(self) -> str:
+        """A fresh request id, for callers that must know it before the
+        request is enqueued (and possibly finished)."""
+        with self._rid_lock:
+            self._rid_seq += 1
+            return f"http-{self._rid_seq}"
+
     def submit(self, *, prompt=None, prompt_len: Optional[int] = None,
                max_new_tokens: Optional[int] = None,
                decode_len: Optional[int] = None,
                slo: Optional[SLOClass] = None,
                on_event: Callable[..., None] = lambda *e: None,
+               rid: Optional[str] = None,
                ) -> Tuple[str, Subscription]:
-        """Enqueue one request; returns its pre-allocated rid at once."""
+        """Enqueue one request; returns its rid (``rid`` from
+        :meth:`new_rid`, or a fresh one) at once."""
         if self.fatal is not None:
             raise RuntimeError(f"session driver is down: {self.fatal}")
-        with self._rid_lock:
-            self._rid_seq += 1
-            rid = f"http-{self._rid_seq}"
+        rid = rid or self.new_rid()
         sub = Subscription(rid, on_event)
         self._cmds.put(("submit", (rid, sub, prompt, prompt_len,
                                    max_new_tokens, decode_len, slo)))

@@ -138,7 +138,10 @@ class ServerConfig:
     port: int = 8000                 # 0 = ephemeral (tests)
     backend: str = "sim"             # "sim" | "engine"
     model: str = "dynaserve"         # name reported by /v1/models
-    arch: str = "qwen2.5-14b"        # sim cost model / engine smoke arch
+    arch: str = "qwen2.5-14b"        # sim cost model / engine arch
+    # engine backend depth: None serves the arch's reduced smoke config,
+    # N its published widths cut to N layers
+    layers: Optional[int] = None
     n_instances: int = 2
     slo: float = 0.100
     admission: bool = False
@@ -181,14 +184,11 @@ def make_session(cfg: ServerConfig):
         default_slo=SLO_CLASSES.get(cfg.default_slo),
         retain_finished=cfg.retain_finished)
     if cfg.backend == "engine":
-        import jax
-        from repro.configs import get_smoke_config
         from repro.engine.backend import EngineBackend
-        from repro.models.model import init_params
+        from repro.launch.serve import engine_model
         from repro.sim.policies import DynaServePolicy
 
-        mcfg = get_smoke_config(cfg.arch)
-        params = init_params(mcfg, jax.random.PRNGKey(0))
+        mcfg, params = engine_model(cfg.arch, cfg.layers)
         backend = EngineBackend(mcfg, params, n_slots=cfg.engine_slots,
                                 max_len=cfg.engine_max_len,
                                 prefix_cache=cfg.prefix_cache,
@@ -566,15 +566,18 @@ class ServingServer:
             except RuntimeError:
                 pass                       # loop already closed (shutdown)
 
+        # register the trace id before the driver sees the request: a
+        # fast backend can finish it before submit() returns, and the
+        # finished trace would keep a default id
+        rid = self.driver.new_rid()
+        trace_id = f"trace-{uuid.uuid4().hex[:16]}"
+        self.tracer.register(rid, trace_id)
         try:
-            rid, _sub = self.driver.submit(
-                prompt=tokens, max_new_tokens=max_new, slo=slo,
-                on_event=on_event)
+            self.driver.submit(prompt=tokens, max_new_tokens=max_new,
+                               slo=slo, on_event=on_event, rid=rid)
         except RuntimeError as e:          # driver down
             writer.write(_error(503, str(e), "server_error"))
             return 503
-        trace_id = f"trace-{uuid.uuid4().hex[:16]}"
-        self.tracer.register(rid, trace_id)
         hdrs = (("x-request-id", rid), ("x-trace-id", trace_id))
         if stream:
             return await self._stream_response(chat, rid, trace_id, max_new,

@@ -175,13 +175,16 @@ def tp_param_specs(cfg, params, axis: str = "model"):
 
 def tp_cache_specs(cache, axis: str = "model"):
     """PartitionSpec tree for a KV cache (dense or paged): the KV-head
-    dim (position -2 of ``(..., KV, hd)``) is sharded; position planes
-    and anything else are replicated."""
+    dim is sharded — position -2 of a dense ``(..., KV, hd)`` cache,
+    -3 of a head-major ``(..., KV, page, hd)`` page pool; position
+    planes and anything else are replicated."""
     def spec(path, x):
         names = _path_names(path)
         leaf = names[-1] if names else ""
         nd = len(x.shape)
-        if leaf in ("k", "v", "k_pages", "v_pages"):
+        if leaf in ("k", "v"):
             return _axis_at(nd, -2, axis)
+        if leaf in ("k_pages", "v_pages"):
+            return _axis_at(nd, -3, axis)
         return P()
     return jax.tree_util.tree_map_with_path(spec, cache)

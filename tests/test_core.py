@@ -20,6 +20,20 @@ def cost():
     return BatchCostModel(get_config("qwen2.5-14b"), A100)
 
 
+# ---------------- hardware table ----------------
+@pytest.mark.parametrize("kind,spec", [("TPU v5 lite", "TPU-v5e"),
+                                       ("cpu", "A100-80G")])
+def test_hardware_for_known_device_kinds(kind, spec):
+    from repro.core.costmodel import hardware_for
+    assert hardware_for(kind).name == spec
+
+
+def test_hardware_for_unknown_device_kind_raises():
+    from repro.core.costmodel import hardware_for
+    with pytest.raises(ValueError, match="no hardware spec"):
+        hardware_for("TPU v4")
+
+
 # ---------------- micro-requests ----------------
 def test_split_special_cases():
     r = Request("r", 0.0, 100, 100)

@@ -144,3 +144,19 @@ def test_vlm_and_audio_frontend_prefill():
             assert np.isfinite(out[slot]).all()
             tok = int(out[slot].argmax())
             pos += 1
+
+
+@pytest.mark.parametrize("layers", [0, 49, -8])
+def test_engine_model_rejects_depth_outside_published(layers):
+    """The full-width entry point cuts depth only within the published
+    layer count (checked before any weight is built)."""
+    from repro.launch.serve import engine_model
+    with pytest.raises(ValueError, match="cannot cut"):
+        engine_model("qwen2.5-14b", layers)
+
+
+def test_engine_model_without_depth_is_the_smoke_config():
+    from repro.launch.serve import engine_model
+    cfg, params = engine_model("qwen2.5-14b", None)
+    assert cfg == get_smoke_config("qwen2.5-14b")
+    assert params["embed"].shape == (cfg.vocab_size, cfg.d_model)

@@ -11,7 +11,7 @@ import pytest
 from repro.kernels.ops import (
     chunked_prefill_attention_op, chunked_prefill_attention_ref,
     gather_pages, paged_decode_attention_op, paged_decode_attention_ref,
-    paged_prefill_attention_op,
+    paged_prefill_attention_op, to_pool_layout,
 )
 
 RNG = np.random.default_rng(7)
@@ -20,6 +20,17 @@ RNG = np.random.default_rng(7)
 def _rand(shape, dtype):
     x = RNG.standard_normal(shape).astype(np.float32)
     return jnp.asarray(x, dtype)
+
+
+def _pool(shape, dtype=jnp.float32):
+    """Random token-major pages (n_pages, page, KV, hd) in the pool's
+    head-major layout (n_pages, KV, page, hd)."""
+    return to_pool_layout(_rand(shape, dtype))[0]
+
+
+def _seq_major(kv):
+    """Dense head-major (B, KV, S, hd) -> token-major (B, S, KV, hd)."""
+    return kv.swapaxes(1, 2)
 
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
@@ -71,8 +82,8 @@ def test_chunked_prefill_zero_offset_is_plain_causal():
 def test_paged_decode_vs_ref(dtype, B, H, KV, hd, page, ppseq):
     n_pages = B * ppseq + 2
     q = _rand((B, H, hd), dtype)
-    kp = _rand((n_pages, page, KV, hd), dtype)
-    vp = _rand((n_pages, page, KV, hd), dtype)
+    kp = _pool((n_pages, page, KV, hd), dtype)
+    vp = _pool((n_pages, page, KV, hd), dtype)
     tbl = jnp.asarray(
         RNG.permutation(n_pages)[:B * ppseq].reshape(B, ppseq), jnp.int32)
     lens = jnp.asarray(RNG.integers(1, page * ppseq + 1, B), jnp.int32)
@@ -93,8 +104,8 @@ def test_paged_decode_gqa_and_page_size_sweep(qpk, page):
     H = KV * qpk
     n_pages = B * ppseq + 1
     q = _rand((B, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    kp = _pool((n_pages, page, KV, hd), jnp.float32)
+    vp = _pool((n_pages, page, KV, hd), jnp.float32)
     tbl = jnp.asarray(
         RNG.permutation(n_pages)[:B * ppseq].reshape(B, ppseq), jnp.int32)
     # ragged: 1 token, mid-page, page-aligned
@@ -118,14 +129,14 @@ def test_paged_prefill_matches_dense_chunked_ref(page, Tq, ctx):
     ppseq = -(-total // page) + 1
     n_pages = B * ppseq + 1
     q = _rand((B, Tq, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    kp = _pool((n_pages, page, KV, hd), jnp.float32)
+    vp = _pool((n_pages, page, KV, hd), jnp.float32)
     tbl = jnp.asarray(
         RNG.permutation(n_pages)[:B * ppseq].reshape(B, ppseq), jnp.int32)
     off = jnp.full((B,), ctx, jnp.int32)
     out = paged_prefill_attention_op(q, kp, vp, tbl, off, interpret=True)
-    k = gather_pages(kp, tbl)
-    v = gather_pages(vp, tbl)
+    k = _seq_major(gather_pages(kp, tbl))
+    v = _seq_major(gather_pages(vp, tbl))
     exp = chunked_prefill_attention_ref(q, k, v, off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-5, atol=2e-5)
@@ -138,13 +149,13 @@ def test_paged_prefill_unwritten_page_slack_is_masked():
     B, Tq, H, KV, hd, page = 1, 6, 4, 2, 32, 8
     ppseq, n_pages = 3, 6
     q = _rand((B, Tq, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    kp = _pool((n_pages, page, KV, hd), jnp.float32)
+    vp = _pool((n_pages, page, KV, hd), jnp.float32)
     tbl = jnp.asarray([[1, 2, 0]], jnp.int32)   # page 0 = sentinel entry
     off = jnp.asarray([4], jnp.int32)           # chunk covers [4, 10)
     out1 = paged_prefill_attention_op(q, kp, vp, tbl, off, interpret=True)
-    kp2 = kp.at[2, 2:].set(1e6).at[0].set(-1e6)  # poison beyond pos 10
-    vp2 = vp.at[2, 2:].set(-1e6).at[0].set(1e6)
+    kp2 = kp.at[2, :, 2:].set(1e6).at[0].set(-1e6)  # poison beyond pos 10
+    vp2 = vp.at[2, :, 2:].set(-1e6).at[0].set(1e6)
     out2 = paged_prefill_attention_op(q, kp2, vp2, tbl, off, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-6)
@@ -170,8 +181,8 @@ def test_paged_decode_ignores_pages_beyond_length():
     B, H, KV, hd, page, ppseq = 1, 4, 2, 32, 8, 4
     n_pages = 8
     q = _rand((B, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    kp = _pool((n_pages, page, KV, hd), jnp.float32)
+    vp = _pool((n_pages, page, KV, hd), jnp.float32)
     tbl = jnp.arange(ppseq, dtype=jnp.int32)[None]
     lens = jnp.array([11], jnp.int32)
     out1 = paged_decode_attention_op(q, kp, vp, tbl, lens, interpret=True)
@@ -197,6 +208,17 @@ QTOL = {"fp8": 0.15, "int8": 0.04}      # abs error vs full-precision oracle
 QPREC = ["fp8", "int8"]
 
 
+def _qpool(shape, prec):
+    """Random token-major pages, quantized per token row, in the pool
+    layout: (full-precision pool, code pool, scale planes, dequantized
+    pool)."""
+    x = _rand(shape, jnp.float32)
+    codes, scales = quantize_kv(x, prec)
+    pool, _ = to_pool_layout(x)
+    cp, sp = to_pool_layout(codes, scales)
+    return pool, cp, sp, to_pool_layout(dequantize_kv(codes, scales))[0]
+
+
 @pytest.mark.parametrize("prec", QPREC)
 def test_quantize_roundtrip_error_bound(prec):
     x = _rand((5, 16, 2, 64), jnp.float32)
@@ -219,18 +241,15 @@ def test_paged_decode_quantized_parity(prec, page, qpk):
     H = KV * qpk
     n_pages = B * ppseq + 1
     q = _rand((B, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    kp, kc, ks, kd = _qpool((n_pages, page, KV, hd), prec)
+    vp, vc, vs, vd = _qpool((n_pages, page, KV, hd), prec)
     tbl = jnp.asarray(
         RNG.permutation(n_pages)[:B * ppseq].reshape(B, ppseq), jnp.int32)
     lens = jnp.asarray([1, page * 2 - 3, page * ppseq], jnp.int32)  # ragged
-    kc, ks = quantize_kv(kp, prec)
-    vc, vs = quantize_kv(vp, prec)
     out = paged_decode_attention_op(q, kc, vc, tbl, lens, ks, vs,
                                     interpret=True)
     # tight vs the oracle on the dequantized values: kernel mechanics only
-    exp_dq = paged_decode_attention_ref(q, dequantize_kv(kc, ks),
-                                        dequantize_kv(vc, vs), tbl, lens)
+    exp_dq = paged_decode_attention_ref(q, kd, vd, tbl, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp_dq),
                                rtol=2e-5, atol=2e-5)
     # loose vs the full-precision oracle: bounded quantization error
@@ -271,21 +290,19 @@ def test_paged_prefill_quantized_gathers_scales(prec):
     ppseq = -(-total // page) + 1
     n_pages = B * ppseq + 1
     q = _rand((B, Tq, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    _, kc, ks, kd = _qpool((n_pages, page, KV, hd), prec)
+    _, vc, vs, vd = _qpool((n_pages, page, KV, hd), prec)
     tbl = jnp.asarray(
         RNG.permutation(n_pages)[:B * ppseq].reshape(B, ppseq), jnp.int32)
     off = jnp.full((B,), ctx, jnp.int32)
-    kc, ks = quantize_kv(kp, prec)
-    vc, vs = quantize_kv(vp, prec)
     out = paged_prefill_attention_op(q, kc, vc, tbl, off, ks, vs,
                                      interpret=True)
-    kd = gather_pages(dequantize_kv(kc, ks), tbl)
-    vd = gather_pages(dequantize_kv(vc, vs), tbl)
-    exp_dq = chunked_prefill_attention_ref(q, kd, vd, off)
+    exp_dq = chunked_prefill_attention_ref(
+        q, _seq_major(gather_pages(kd, tbl)),
+        _seq_major(gather_pages(vd, tbl)), off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp_dq),
                                rtol=2e-5, atol=2e-5)
-    assert gather_scales(ks, tbl).shape == (B, ppseq * page)
+    assert gather_scales(ks, tbl).shape == (B, 1, ppseq * page)
 
 
 @pytest.mark.parametrize("prec", QPREC)
@@ -295,12 +312,10 @@ def test_paged_decode_quantized_ignores_poison_pages(prec):
     B, H, KV, hd, page, ppseq = 1, 4, 2, 32, 8, 4
     n_pages = 8
     q = _rand((B, H, hd), jnp.float32)
-    kp = _rand((n_pages, page, KV, hd), jnp.float32)
-    vp = _rand((n_pages, page, KV, hd), jnp.float32)
+    _, kc, ks, _ = _qpool((n_pages, page, KV, hd), prec)
+    _, vc, vs, _ = _qpool((n_pages, page, KV, hd), prec)
     tbl = jnp.arange(ppseq, dtype=jnp.int32)[None]
     lens = jnp.array([11], jnp.int32)
-    kc, ks = quantize_kv(kp, prec)
-    vc, vs = quantize_kv(vp, prec)
     out1 = paged_decode_attention_op(q, kc, vc, tbl, lens, ks, vs,
                                      interpret=True)
     qmax = 127 if prec == "int8" else 448

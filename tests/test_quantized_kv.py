@@ -129,7 +129,9 @@ def test_engine_quantized_generation(prec):
     blk = eng.cache["blocks"][0]
     assert blk["k_pages"].dtype == kv_storage_dtype(prec)
     assert blk["k_scales"].dtype == jnp.float32
-    assert blk["v_scales"].shape == blk["v_pages"].shape[:-2]
+    # head-major pages (G, n, KV, page, hd); one scale per token (G, n, 1, page)
+    G, n, _, page, _ = blk["v_pages"].shape
+    assert blk["v_scales"].shape == (G, n, 1, page)
 
     bf16 = _engine(cfg, params, "bf16")
     bf16.run_batch([BatchItem(bf16.alloc("r"), prompt, 0)])

@@ -106,7 +106,6 @@ def test_moe_ep_routing_equivalence():
     replicated router/capacity ranking means all shards agree on the
     dispatch, and the combine psum sums each token exactly once."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map_compat
     from repro.models.layers import moe_fwd
     from repro.models.tp import tp_context
 
@@ -130,7 +129,8 @@ def test_moe_ep_routing_equivalence():
         with tp_context("model"):
             return moe_fwd(p, x, cfg)
 
-    y, aux = shard_map_compat(body, mesh, (p_specs, P()), (P(), P()))(p, x)
+    y, aux = jax.shard_map(body, mesh=mesh, in_specs=(p_specs, P()),
+                           out_specs=(P(), P()), check_vma=False)(p, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
@@ -351,3 +351,25 @@ def test_engine_device_shortage_hint():
     backend = EngineBackend(cfg, params, devices_per_instance=n)
     with pytest.raises(ValueError, match="xla_force_host_platform"):
         backend.spawn(0)
+
+
+@multi
+def test_one_device_instances_round_robin_share_weights():
+    """One-device instances spread over the visible devices (instance
+    iid on device iid % n), and instances on one device share one placed
+    copy of the weights."""
+    from repro.engine.backend import EngineBackend
+    cfg = get_smoke_config(DENSE)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    n = jax.device_count()
+    backend = EngineBackend(cfg, params, n_slots=2, max_len=32)
+    for iid in range(n + 1):
+        backend.spawn(iid)
+    engines = [backend.engines[i] for i in range(n + 1)]
+    for iid, eng in enumerate(engines):
+        want = {jax.devices()[iid % n]}
+        assert eng.params["embed"].devices() == want
+        assert eng.cache["blocks"][0]["k_pages"].devices() == want
+    ptr = lambda e: e.params["embed"].unsafe_buffer_pointer()
+    assert ptr(engines[n]) == ptr(engines[0])
+    assert ptr(engines[1]) != ptr(engines[0])
