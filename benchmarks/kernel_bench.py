@@ -27,8 +27,9 @@ def main(csv: Csv | None = None):
     k = jnp.asarray(rng.standard_normal((B, S, KV, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, KV, hd)), jnp.float32)
     off = jnp.zeros((B,), jnp.int32)
+    qh, kh, vh = (x.swapaxes(1, 2) for x in (q, k, v))   # head-major op
     _, us = timed(lambda: chunked_prefill_attention_op(
-        q, k, v, off, bq=32, bk=64, interpret=True).block_until_ready())
+        qh, kh, vh, off, bq=32, bk=64, interpret=True).block_until_ready())
     _, us_ref = timed(lambda: chunked_prefill_attention_ref(
         q, k, v, off).block_until_ready())
     flops = 4 * B * Tq * S * H * hd

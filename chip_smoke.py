@@ -1,7 +1,7 @@
 """Smoke run of the served path on TPU: proves the system starts on the chip.
 
 One process, no children.  Serves Qwen2.5-14B at its published widths,
-cut in depth to fit one v5e chip, bf16, weights random from ``--seed``:
+cut in depth to fit one v5e chip, bf16, weights random from ``SEED``:
 
 1. ``ServeSession`` + ``EngineBackend`` through ``repro.launch.serve``'s
    engine path: 2 instances on the chip, the DynaServe policy, open-loop
@@ -21,7 +21,7 @@ instance on chip 0 to instances on chips 1-3, and a TP=4 instance.
 
 Times printed are smoke readings, not benchmarks.  The last line of
 stdout is ``{"ok": true, "device": {...}}``; any failure exits non-zero
-before it.  Usage: ``python chip_smoke.py [--chips 4] [--layers N]``.
+before it.  Usage: ``python chip_smoke.py [--chips 4]``.
 """
 from __future__ import annotations
 
@@ -37,6 +37,8 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
 ARCH = "qwen2.5-14b"
+LAYERS = 8            # depth cut of the 48 layers: 7.5 GB of bf16 weights
+SEED = 0              # weights, prompts and the request trace
 PROMPT = 512          # logits-check prompt: one 512-token prefill chunk
 DECODE = 8            # decode steps compared after the prefill
 
@@ -125,12 +127,12 @@ def step_has_kernel(eng, key):
     return "tpu_custom_call" in text
 
 
-def check_logits(cfg, params, seed):
+def check_logits(cfg, params):
     import jax
     from repro.engine.runner import InstanceEngine
     from repro.models.model import forward
 
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     prompt = rng.integers(0, cfg.vocab_size, PROMPT).astype(np.int32)
     eng = InstanceEngine(cfg, params, n_slots=1, max_len=PROMPT + DECODE,
                          devices=[jax.devices()[0]])
@@ -157,13 +159,13 @@ def check_logits(cfg, params, seed):
         raise SystemExit("FAIL: a step program runs without the kernels")
 
 
-def serve_session(cfg, params, layers, seed):
+def serve_session(cfg, params):
     from repro.launch import serve
     args = serve.build_parser().parse_args([
-        "--backend", "engine", "--arch", ARCH, "--layers", str(layers),
+        "--backend", "engine", "--arch", ARCH, "--layers", str(LAYERS),
         "--instances", "2", "--policy", "dyna", "--open-loop",
         "--requests", "8", "--prompt-len", "1024", "--max-new", "64",
-        "--seed", str(seed)])
+        "--seed", str(SEED)])
     m, session = serve.serve_engine(args, model=(cfg, params))
     moved = session.backend.kv_bytes_moved
     print(f"serve: {m.completed}/{m.offered} requests completed, "
@@ -184,13 +186,13 @@ def _post(port, body):
     return resp.status, data
 
 
-def serve_http(layers, seed):
+def serve_http():
     from repro.serving.http import ServerConfig, ServingServer
     srv = ServingServer(ServerConfig(
-        port=0, backend="engine", arch=ARCH, layers=layers,
+        port=0, backend="engine", arch=ARCH, layers=LAYERS,
         n_instances=2, engine_slots=16, engine_max_len=1120)).start()
     try:
-        rng = np.random.default_rng(seed + 1)
+        rng = np.random.default_rng(SEED + 1)
         prompts = [rng.integers(0, 1000, n).tolist() for n in (300, 40)]
         status, data = _post(srv.port, {"prompt": prompts[0],
                                         "max_tokens": 16})
@@ -210,14 +212,14 @@ def serve_http(layers, seed):
         srv.stop()
 
 
-def four_chips(cfg, params, seed):
+def four_chips(cfg, params):
     """Cross-chip handoff and TP=4, each against chip 0 alone."""
     import jax
     from repro.engine.backend import EngineBackend
     from repro.engine.runner import BatchItem, InstanceEngine
 
     devs = jax.devices()
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     prompt = rng.integers(0, cfg.vocab_size, PROMPT).astype(np.int32)
     be = EngineBackend(cfg, params, n_slots=2, max_len=PROMPT + DECODE)
     for iid in range(4):
@@ -260,9 +262,6 @@ def four_chips(cfg, params, seed):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--layers", type=int, default=8,
-                    help="depth cut of the 48-layer model")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     import jax
@@ -276,15 +275,16 @@ def main(argv=None) -> int:
               f"{len(jax.devices())} device(s)", file=sys.stderr)
         return 1
     sys.path.insert(0, str(ROOT / "src"))
-    from repro.launch import serve
+    from repro.launch.serve import use_compile_cache
+    from repro.models.model import engine_model
 
-    cache_dir = serve.use_compile_cache()
+    cache_dir = use_compile_cache()
     clock = CompileClock(jax)
     print(f"device_kind {dev.device_kind!r}, {len(jax.devices())} "
           f"device(s); compile cache {cache_dir}", flush=True)
 
     t0, c0, h0 = time.monotonic(), clock.seconds, clock.hits
-    cfg, params = serve.engine_model(ARCH, args.layers, args.seed)
+    cfg, params = engine_model(ARCH, LAYERS, SEED)
     jax.block_until_ready(params)
     n_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
     kv_tok = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd * 2
@@ -297,13 +297,11 @@ def main(argv=None) -> int:
 
     if args.chips == 4:
         t0, c0, h0 = time.monotonic(), clock.seconds, clock.hits
-        four_chips(cfg, params, args.seed)
+        four_chips(cfg, params)
         phase("4-chip", clock, dev, t0, c0, h0)
     else:
-        for name, fn in (("serve", lambda: serve_session(
-                              cfg, params, args.layers, args.seed)),
-                         ("logits", lambda: check_logits(
-                              cfg, params, args.seed))):
+        for name, fn in (("serve", lambda: serve_session(cfg, params)),
+                         ("logits", lambda: check_logits(cfg, params))):
             t0, c0, h0 = time.monotonic(), clock.seconds, clock.hits
             fn()
             gc.collect()
@@ -311,7 +309,7 @@ def main(argv=None) -> int:
         del params
         gc.collect()
         t0, c0, h0 = time.monotonic(), clock.seconds, clock.hits
-        serve_http(args.layers, args.seed)
+        serve_http()
         phase("http", clock, dev, t0, c0, h0)
     print(f"compile total: {clock.seconds:.1f} s, {clock.hits} "
           f"persistent-cache hits (smoke reading, not a benchmark)")

@@ -7,10 +7,10 @@ boundaries here so callers can use ragged sizes.
 Layouts.  A paged pool stores each page head-major,
 ``(n_pages, KV, page, hd)``, with per-token scale planes
 ``(n_pages, 1, page)`` for quantized formats: the shapes whose
-(second-minor, minor) block dimensions Mosaic accepts.  Handoff pieces
-and callers of the dense ops use the token-major form
-``(..., n, page, KV, hd)`` / ``(..., n, page)``; ``to_pool_layout`` and
-``from_pool_layout`` convert between the two.
+(second-minor, minor) block dimensions Mosaic accepts; dense chunked
+prefill is head-major for the same reason.  Handoff pieces use the
+token-major form ``(..., n, page, KV, hd)`` / ``(..., n, page)``;
+``to_pool_layout`` and ``from_pool_layout`` convert between the two.
 """
 from __future__ import annotations
 
@@ -105,9 +105,16 @@ def _pad_to(x, axis: int, mult: int):
     return jnp.pad(x, widths)
 
 
-def _prefill_head_major(q, k, v, offsets, ks, vs, bq, bk, interpret):
-    """Head-major chunked prefill: q (B,H,Tq,hd), k/v (B,KV,S,hd),
-    scales (B,1,S) -> (B,H,Tq,hd).  Pads Tq/S to tile multiples."""
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
+def chunked_prefill_attention_op(q, k, v, offsets, k_scales=None,
+                                 v_scales=None, *, bq: int = 128,
+                                 bk: int = 128, interpret: bool | None = None):
+    """Chunked prefill on head-major operands: q (B,H,Tq,hd), k/v
+    (B,KV,S,hd) -> (B,H,Tq,hd).  Pads Tq/S to tile multiples, runs the
+    kernel, un-pads.  ``interpret=None`` means :func:`interpret_mode`.
+
+    ``k_scales``/``v_scales``: optional (B, 1, S) per-token dequant
+    scales when k/v hold quantized codes."""
     if interpret is None:
         interpret = interpret_mode()
     Tq = q.shape[2]
@@ -116,29 +123,10 @@ def _prefill_head_major(q, k, v, offsets, ks, vs, bq, bk, interpret):
     out = chunked_prefill_attention(
         _pad_to(q, 2, bq_eff), _pad_to(k, 2, bk_eff), _pad_to(v, 2, bk_eff),
         offsets.astype(jnp.int32),
-        None if ks is None else _pad_to(ks, 2, bk_eff),
-        None if vs is None else _pad_to(vs, 2, bk_eff),
+        None if k_scales is None else _pad_to(k_scales, 2, bk_eff),
+        None if v_scales is None else _pad_to(v_scales, 2, bk_eff),
         bq=bq_eff, bk=bk_eff, interpret=interpret)
     return out[:, :, :Tq]
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
-def chunked_prefill_attention_op(q, k, v, offsets, k_scales=None,
-                                 v_scales=None, *, bq: int = 128,
-                                 bk: int = 128, interpret: bool | None = None):
-    """Public op on token-major operands: q (B,Tq,H,hd), k/v (B,S,KV,hd)
-    -> (B,Tq,H,hd).  Transposes to the kernel's head-major layout, pads,
-    runs the kernel, un-pads.  ``interpret=None`` means
-    :func:`interpret_mode`.
-
-    ``k_scales``/``v_scales``: optional (B, S) per-token dequant scales
-    when k/v hold quantized codes."""
-    ks = None if k_scales is None else k_scales[:, None]
-    vs = None if v_scales is None else v_scales[:, None]
-    out = _prefill_head_major(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                              v.swapaxes(1, 2), offsets, ks, vs,
-                              bq, bk, interpret)
-    return out.swapaxes(1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -196,8 +184,9 @@ def paged_prefill_attention_op(q, k_pages, v_pages, block_tables, offsets,
     v = gather_pages(v_pages, tbl)
     ks = None if k_scales is None else gather_scales(k_scales, tbl)
     vs = None if v_scales is None else gather_scales(v_scales, tbl)
-    out = _prefill_head_major(q.swapaxes(1, 2), k, v, offsets, ks, vs,
-                              bq, bk, interpret)
+    out = chunked_prefill_attention_op(q.swapaxes(1, 2), k, v, offsets,
+                                       ks, vs, bq=bq, bk=bk,
+                                       interpret=interpret)
     return out.swapaxes(1, 2)
 
 

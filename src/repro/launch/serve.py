@@ -95,30 +95,6 @@ def use_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-def engine_model(arch: str, layers: Optional[int], seed: int = 0):
-    """``(cfg, params)`` for the engine backend, weights random from
-    ``seed``.  ``layers=None`` is the arch's reduced CPU smoke config;
-    an int keeps every published width and cuts the depth to that many
-    layers."""
-    import jax
-    from repro.configs import get_config, get_smoke_config
-    from repro.models.model import init_params
-
-    if layers is None:
-        cfg = get_smoke_config(arch)
-    else:
-        cfg = get_config(arch)
-        if not 0 < layers <= cfg.n_layers or layers % cfg.pattern_len:
-            raise ValueError(
-                f"{arch}: cannot cut {cfg.n_layers} layers (pattern of "
-                f"{cfg.pattern_len}) to {layers}")
-        cfg = cfg.with_(n_layers=layers)
-    # one jitted program: no eager per-tensor f32 temporaries on device
-    params = jax.jit(init_params, static_argnums=0)(
-        cfg, jax.random.PRNGKey(seed))
-    return cfg, params
-
-
 def mini_trace(n: int, qps: float, seed: int,
                slo_mix: Optional[Dict[str, float]],
                p_max: int = 48, d_max: int = 16) -> List[Request]:
@@ -205,11 +181,8 @@ def _finish_recorder(rec, args) -> None:
 
 def engine_layers(args) -> Optional[int]:
     """The engine's depth: ``None`` (the smoke config) under --smoke,
-    else --layers, defaulting to the published depth."""
-    if args.smoke:
-        return None
-    from repro.configs import get_config
-    return args.layers or get_config(args.arch).n_layers
+    else --layers (``main`` refuses an engine run with neither)."""
+    return None if args.smoke else args.layers
 
 
 def serve_engine(args, model=None) -> Tuple[SessionMetrics, ServeSession]:
@@ -217,6 +190,7 @@ def serve_engine(args, model=None) -> Tuple[SessionMetrics, ServeSession]:
     ``engine_model`` result to reuse (built from ``args`` if None)."""
     dpi = parse_devices(args.devices_per_instance)
     from repro.engine.backend import EngineBackend
+    from repro.models.model import engine_model
     from repro.sim.policies import DynaServePolicy
 
     cfg, params = model or engine_model(args.arch, engine_layers(args),
@@ -365,9 +339,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced model + tiny trace (CI-sized)")
     ap.add_argument("--layers", type=int, default=None,
-                    help="engine backend without --smoke: serve the "
-                         "published widths of --arch cut to this many "
-                         "layers (default: every layer)")
+                    help="engine backend without --smoke (required "
+                         "there): serve the published widths of --arch "
+                         "cut to this many layers")
     ap.add_argument("--open-loop", action="store_true",
                     help="honor arrival timestamps on the wall clock "
                          "(engine backend; the simulator is always "
@@ -423,9 +397,13 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
-    args = build_parser().parse_args(argv)
+    ap = build_parser()
+    args = ap.parse_args(argv)
     backend = args.backend or ("engine" if args.smoke and not args.http
                                else "sim")
+    if backend == "engine" and not args.smoke and args.layers is None:
+        ap.error("the engine backend serves --smoke (the reduced config) "
+                 "or --layers N (published widths, N layers); pick one")
     if backend == "engine":
         _ensure_host_devices(args.instances * _max_width(
             parse_devices(args.devices_per_instance)))

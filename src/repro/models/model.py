@@ -132,6 +132,28 @@ def init_params(cfg: ModelConfig, key: Optional[jax.Array] = None,
     return params
 
 
+def engine_model(arch: str, layers: Optional[int], seed: int = 0):
+    """``(cfg, params)`` for the engine backend, weights random from
+    ``seed``.  ``layers=None`` is the arch's reduced CPU smoke config;
+    an int keeps every published width and cuts the depth to that many
+    layers."""
+    from repro.configs import get_config, get_smoke_config
+
+    if layers is None:
+        cfg = get_smoke_config(arch)
+    else:
+        cfg = get_config(arch)
+        if not 0 < layers <= cfg.n_layers or layers % cfg.pattern_len:
+            raise ValueError(
+                f"{arch}: cannot cut {cfg.n_layers} layers (pattern of "
+                f"{cfg.pattern_len}) to {layers}")
+        cfg = cfg.with_(n_layers=layers)
+    # one jitted program: no eager per-tensor f32 temporaries on device
+    params = jax.jit(init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed))
+    return cfg, params
+
+
 # ==========================================================================
 # cache construction
 # ==========================================================================

@@ -108,22 +108,21 @@ class SessionDriver:
             self._rid_seq += 1
             return f"http-{self._rid_seq}"
 
-    def submit(self, *, prompt=None, prompt_len: Optional[int] = None,
+    def submit(self, rid: str, *, prompt=None,
+               prompt_len: Optional[int] = None,
                max_new_tokens: Optional[int] = None,
                decode_len: Optional[int] = None,
                slo: Optional[SLOClass] = None,
                on_event: Callable[..., None] = lambda *e: None,
-               rid: Optional[str] = None,
-               ) -> Tuple[str, Subscription]:
-        """Enqueue one request; returns its rid (``rid`` from
-        :meth:`new_rid`, or a fresh one) at once."""
+               ) -> Subscription:
+        """Enqueue request ``rid`` (from :meth:`new_rid`); returns at
+        once."""
         if self.fatal is not None:
             raise RuntimeError(f"session driver is down: {self.fatal}")
-        rid = rid or self.new_rid()
         sub = Subscription(rid, on_event)
         self._cmds.put(("submit", (rid, sub, prompt, prompt_len,
                                    max_new_tokens, decode_len, slo)))
-        return rid, sub
+        return sub
 
     def cancel(self, rid: str) -> None:
         self._cmds.put(("cancel", (rid,)))

@@ -185,7 +185,7 @@ def make_session(cfg: ServerConfig):
         retain_finished=cfg.retain_finished)
     if cfg.backend == "engine":
         from repro.engine.backend import EngineBackend
-        from repro.launch.serve import engine_model
+        from repro.models.model import engine_model
         from repro.sim.policies import DynaServePolicy
 
         mcfg, params = engine_model(cfg.arch, cfg.layers)
@@ -573,8 +573,8 @@ class ServingServer:
         trace_id = f"trace-{uuid.uuid4().hex[:16]}"
         self.tracer.register(rid, trace_id)
         try:
-            self.driver.submit(prompt=tokens, max_new_tokens=max_new,
-                               slo=slo, on_event=on_event, rid=rid)
+            self.driver.submit(rid, prompt=tokens, max_new_tokens=max_new,
+                               slo=slo, on_event=on_event)
         except RuntimeError as e:          # driver down
             writer.write(_error(503, str(e), "server_error"))
             return 503

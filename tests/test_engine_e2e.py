@@ -150,13 +150,52 @@ def test_vlm_and_audio_frontend_prefill():
 def test_engine_model_rejects_depth_outside_published(layers):
     """The full-width entry point cuts depth only within the published
     layer count (checked before any weight is built)."""
-    from repro.launch.serve import engine_model
+    from repro.models.model import engine_model
     with pytest.raises(ValueError, match="cannot cut"):
         engine_model("qwen2.5-14b", layers)
 
 
 def test_engine_model_without_depth_is_the_smoke_config():
-    from repro.launch.serve import engine_model
+    from repro.models.model import engine_model
     cfg, params = engine_model("qwen2.5-14b", None)
     assert cfg == get_smoke_config("qwen2.5-14b")
     assert params["embed"].shape == (cfg.vocab_size, cfg.d_model)
+
+
+@pytest.mark.parametrize("argv", [["--backend", "engine"],
+                                  ["--http", "--backend", "engine"]])
+def test_serve_engine_needs_smoke_or_layers(argv, capsys):
+    """The engine backend never defaults to the full published depth
+    (Qwen2.5-14B's 48 layers do not fit one chip): without --smoke or
+    --layers the launcher refuses before building anything."""
+    from repro.launch import serve
+    with pytest.raises(SystemExit) as exc:
+        serve.main(argv)
+    assert exc.value.code == 2
+    assert "--layers N" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_use_compile_cache(env_dir, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins when set (JAX reads it at start-up
+    and the helper sets nothing); otherwise the cache sits at the fixed
+    ``<checkout>/.jax_cache``.  Run in a fresh interpreter: JAX reads
+    the variable only at import, and the cache directory is global."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\nfrom repro.launch.serve import use_compile_cache\n"
+         "d = use_compile_cache()\n"
+         "assert d == jax.config.jax_compilation_cache_dir\nprint(d)"],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    want = tmp_path / env_dir if env_dir else root / ".jax_cache"
+    assert out.stdout.strip().splitlines()[-1] == str(want)

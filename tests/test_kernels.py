@@ -33,6 +33,16 @@ def _seq_major(kv):
     return kv.swapaxes(1, 2)
 
 
+def _prefill(q, k, v, off, ks=None, vs=None, **kw):
+    """The head-major chunked-prefill op on the oracle's token-major
+    operands: q (B,Tq,H,hd), k/v (B,S,KV,hd), scales (B,S)."""
+    out = chunked_prefill_attention_op(
+        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), off,
+        None if ks is None else ks[:, None],
+        None if vs is None else vs[:, None], interpret=True, **kw)
+    return out.swapaxes(1, 2)
+
+
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
@@ -48,8 +58,7 @@ def test_chunked_prefill_vs_ref(dtype, B, Tq, S, H, KV, hd, bq, bk):
     k = _rand((B, S, KV, hd), dtype)
     v = _rand((B, S, KV, hd), dtype)
     off = jnp.asarray(RNG.integers(0, S - Tq, B), jnp.int32)
-    out = chunked_prefill_attention_op(q, k, v, off, bq=bq, bk=bk,
-                                       interpret=True)
+    out = _prefill(q, k, v, off, bq=bq, bk=bk)
     exp = chunked_prefill_attention_ref(q, k, v, off)
     tol = TOL[dtype]
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -63,8 +72,7 @@ def test_chunked_prefill_zero_offset_is_plain_causal():
     q = _rand((B, T, H, hd), jnp.float32)
     k = _rand((B, T, H, hd), jnp.float32)
     v = _rand((B, T, H, hd), jnp.float32)
-    out = chunked_prefill_attention_op(q, k, v, jnp.zeros(B, jnp.int32),
-                                       bq=8, bk=8, interpret=True)
+    out = _prefill(q, k, v, jnp.zeros(B, jnp.int32), bq=8, bk=8)
     scores = jnp.einsum("bthd,bshd->bhts", q, k) / np.sqrt(hd)
     mask = jnp.tril(jnp.ones((T, T), bool))
     scores = jnp.where(mask[None, None], scores, -1e30)
@@ -169,8 +177,7 @@ def test_chunked_prefill_per_row_ragged_offsets():
     k = _rand((B, S, H, hd), jnp.float32)
     v = _rand((B, S, H, hd), jnp.float32)
     off = jnp.asarray([0, 13, 32 - Tq], jnp.int32)
-    out = chunked_prefill_attention_op(q, k, v, off, bq=8, bk=8,
-                                       interpret=True)
+    out = _prefill(q, k, v, off, bq=8, bk=8)
     exp = chunked_prefill_attention_ref(q, k, v, off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-5, atol=2e-5)
@@ -271,8 +278,7 @@ def test_chunked_prefill_quantized_parity(prec, B, Tq, S, KV, qpk, bq, bk):
     off = jnp.asarray(RNG.integers(0, S - Tq, B), jnp.int32)
     kc, ks = quantize_kv(k, prec)
     vc, vs = quantize_kv(v, prec)
-    out = chunked_prefill_attention_op(q, kc, vc, off, ks, vs,
-                                       bq=bq, bk=bk, interpret=True)
+    out = _prefill(q, kc, vc, off, ks, vs, bq=bq, bk=bk)
     exp_dq = chunked_prefill_attention_ref(q, dequantize_kv(kc, ks),
                                            dequantize_kv(vc, vs), off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp_dq),

@@ -75,10 +75,10 @@ def test_paged_decode_compiles(v5e, width, prec, page):
 def test_chunked_prefill_compiles(v5e, width, prec):
     H, KV = WIDTHS[width]
     B, T, S = 2, 256, 1024
-    kv = [((B, S, KV, HD), DTYPES[prec])] * 2
-    scales = [((B, S), jnp.float32)] * 2 if prec != "bf16" else []
+    kv = [((B, KV, S, HD), DTYPES[prec])] * 2
+    scales = [((B, 1, S), jnp.float32)] * 2 if prec != "bf16" else []
     assert _compiles_to_kernel(
-        chunked_prefill_attention_op, v5e, ((B, T, H, HD), jnp.bfloat16),
+        chunked_prefill_attention_op, v5e, ((B, H, T, HD), jnp.bfloat16),
         *kv, ((B,), jnp.int32), *scales)
 
 
