@@ -61,6 +61,7 @@ from repro.core.predictor import ExecutionPredictor, QueuedWork
 from repro.core.request import (
     MicroRequest, Request, RequestState, SLOClass,
 )
+from repro.utils import spans
 
 # Process-wide default for ``SessionConfig.overlap=None`` — the test
 # harness flips this (pytest --overlap) to rerun every existing suite
@@ -139,6 +140,7 @@ class ExecHandle:
     token: object = None
     result: Optional["ExecResult"] = None
     overlapped: bool = False
+    seq: int = -1                 # the backend's step number, when traced
 
     @property
     def micros(self) -> set:
@@ -188,7 +190,6 @@ class InstanceState:
         # accounting
         self.busy_time = 0.0
         self.flops_done = 0.0
-        self.bytes_done = 0.0
         self.kv_tokens_resident = 0
 
     @property
@@ -340,6 +341,11 @@ class Backend:
     def poll(self, token) -> bool:
         """True when ``collect(token)`` would not block."""
         return True
+
+    def step_seq(self, iid: int) -> int:
+        """The number the instance's next step carries in profiler
+        spans (-1: the substrate numbers no steps)."""
+        return -1
 
     def collect(self, token) -> ExecResult:
         """Block until the dispatched batch finishes; return its result."""
@@ -794,7 +800,11 @@ class ServeSession:
             return
         wall = self._wall()
         if self.cfg.open_loop and t > wall:
-            _time.sleep(t - wall)
+            # idle by design: nothing is due before t
+            with spans.span("session.sleep") as sp:
+                if sp is not None:
+                    sp.set_metadata(us=int((t - wall) * 1e6))
+                _time.sleep(t - wall)
             wall = self._wall()
         self.now = max(self.now, wall)
 
@@ -1320,6 +1330,17 @@ class ServeSession:
 
     # ---------------- arrival ----------------
     def _on_arrival(self, r: Request) -> None:
+        now = self.now
+        with spans.span("session.arrival") as sp:
+            self._arrive(r)
+            if sp is not None:
+                sp.set_metadata(
+                    rid=r.rid, now_us=int(now * 1e6),
+                    late_us=int((now - r.arrival) * 1e6),
+                    admitted=int(r.state != RequestState.REJECTED))
+
+    def _arrive(self, r: Request) -> None:
+        """Admission verdict, global-scheduler split and placement."""
         self._arrivals_left -= 1
         if r.state != RequestState.QUEUED:
             # a reused trace object carries the previous run's terminal
@@ -1574,8 +1595,44 @@ class ServeSession:
                 break
 
     def _dispatch_one(self, inst: InstanceState):
-        if not inst.has_work(self.now):
+        with spans.span("session.compose") as sp:
+            h = self._next_batch(inst)
+            if sp is not None:
+                sp.set_metadata(seq=self.backend.step_seq(inst.iid),
+                                now_us=int(self.now * 1e6),
+                                prefill_rows=len(h.grants) if h else 0,
+                                decode_rows=len(h.decs) if h else 0)
+        if h is None:
             return False
+        inst.inflight.append(h)
+        if self._overlap:
+            h.overlapped = True
+            out = self.backend.dispatch(inst, h.grants, h.decs, now=self.now)
+            if isinstance(out, ExecResult):
+                # virtual (or degenerate-synchronous) substrate: the
+                # completion time is already known
+                h.result = out
+                self._push(self.now + (out.latency if out.deferred
+                                       else 0.0), "batch_done", h)
+            else:
+                h.token = out
+                self._push(self.now, "collect", h)
+            return True
+        res = self.backend.execute(inst, h.grants, h.decs)
+        h.result = res
+        if res.deferred:
+            self._push(self.now + res.latency, "batch_done", h)
+            return True
+        # synchronous substrate: the wall clock already advanced
+        self._advance(self._wall())
+        self._on_batch_done(h)
+        return "inline"
+
+    def _next_batch(self, inst: InstanceState) -> Optional[ExecHandle]:
+        """Compose the instance's next batch (preempting for memory and
+        claiming cached prefixes as needed); None when nothing runs."""
+        if not inst.has_work(self.now):
+            return None
         plan, pf, dc = self._compose_batch(inst)
         # Dispatch-ahead gate: pipelining pays off only for prefill
         # chunk streams (pure compute, no cross-batch data hazard).
@@ -1591,7 +1648,7 @@ class ServeSession:
         # identical to the synchronous loop.
         if inst.inflight and (plan.decodes or
                               any(h.plan.dnum for h in inst.inflight)):
-            return False
+            return None
         # memory-starved with runnable work: preempt (possibly several
         # victims — deep overcommit needs more than one) and retry;
         # otherwise defer — pages free as other requests finish
@@ -1601,7 +1658,7 @@ class ServeSession:
             guard -= 1
             plan, pf, dc = self._compose_batch(inst)
         if not plan.decodes and not plan.prefills:
-            return False
+            return None
         # map back to MicroState; apply late prefix-cache claims now —
         # the scheduler granted the cached head budget-free, the claim
         # splices the pages and advances pos, and only the computed
@@ -1616,7 +1673,7 @@ class ServeSession:
                 grants.append((m, g))
         decs = [by_rid[w.rid] for w in plan.decodes]
         if not grants and not decs:
-            return False
+            return None
         if self._dec:
             self.record_decision("batch", {
                 "iid": inst.iid,
@@ -1628,44 +1685,39 @@ class ServeSession:
                 "starved": plan.starved,
                 "cached_tokens": plan.cached_tokens})
         h = ExecHandle(inst.iid, grants, decs, plan, self.now)
+        tracing = spans.active()
+        if tracing:
+            h.seq = self.backend.step_seq(inst.iid)
         for m in h.micros:
-            self._to(m.mr.parent,
+            req = m.mr.parent
+            if tracing and req.state == RequestState.ADMITTED:
+                # the request's first batch: its wait in the queue
+                due = self.req_states[req.rid].arrival
+                with spans.span("session.start", rid=req.rid, seq=h.seq,
+                                wait_us=int((self.now - due) * 1e6)):
+                    pass
+            self._to(req,
                      RequestState.RUNNING_BETA if m.mr.role == "beta"
                      else RequestState.RUNNING_ALPHA)
         items = ([WorkItem("prefill", g, m.pos) for m, g in grants] +
                  [WorkItem("decode", 1, m.pos) for m in decs])
         inst.flops_done += self.cost.flops(items)
-        inst.bytes_done += self.cost.bytes_moved(items)
-        inst.inflight.append(h)
-        if self._overlap:
-            h.overlapped = True
-            out = self.backend.dispatch(inst, grants, decs, now=self.now)
-            if isinstance(out, ExecResult):
-                # virtual (or degenerate-synchronous) substrate: the
-                # completion time is already known
-                h.result = out
-                self._push(self.now + (out.latency if out.deferred
-                                       else 0.0), "batch_done", h)
-            else:
-                h.token = out
-                self._push(self.now, "collect", h)
-            return True
-        res = self.backend.execute(inst, grants, decs)
-        h.result = res
-        if res.deferred:
-            self._push(self.now + res.latency, "batch_done", h)
-            return True
-        # synchronous substrate: the wall clock already advanced
-        self._advance(self._wall())
-        self._on_batch_done(h)
-        return "inline"
+        return h
 
     def _on_batch_done(self, h: ExecHandle) -> None:
-        iid = h.iid
-        inst = self.instances[iid]
         if h.result is None:
             h.result = self.backend.collect(h.token)
             self._advance(self._wall())
+        with spans.span("session.batch_done") as sp:
+            if sp is not None:
+                sp.set_metadata(seq=h.seq, now_us=int(self.now * 1e6))
+            self._apply_result(h)
+
+    def _apply_result(self, h: ExecHandle) -> None:
+        """Progress, token emission, observers and finishing for a
+        batch whose result is in."""
+        iid = h.iid
+        inst = self.instances[iid]
         grants, decs, plan, res = h.grants, h.decs, h.plan, h.result
         self._batches_done += 1
         if h in inst.inflight:

@@ -32,6 +32,7 @@ from repro.engine.runner import (
 from repro.engine.sampling import sample
 from repro.models.config import ModelConfig
 from repro.models.model import supports_paged_kv
+from repro.utils import spans
 
 
 @dataclasses.dataclass
@@ -442,6 +443,10 @@ class EngineBackend(Backend):
                 eng.preempt(loc[1])
 
     # ---------------- execution ----------------
+    def step_seq(self, iid: int) -> int:
+        eng = self.engines.get(iid)
+        return eng.iterations if eng is not None else -1
+
     def _build(self, grants: Sequence[Tuple[MicroState, int]],
                decs: Sequence[MicroState]) \
             -> Tuple[List[BatchItem], List[Tuple[MicroState, int]]]:
@@ -479,7 +484,10 @@ class EngineBackend(Backend):
         are (nearly) ready — host-side scheduling and KV streaming
         happen in between."""
         eng = self.engines[inst.iid]
-        items, sampled = self._build(grants, decs)
+        with spans.span("backend.build") as sp:
+            if sp is not None:
+                sp.set_metadata(seq=eng.iterations)
+            items, sampled = self._build(grants, decs)
         t0 = time.monotonic()
         step = eng.dispatch_batch(items)
         return _EngineToken(eng=eng, step=step, sampled=sampled, t0=t0)
@@ -492,11 +500,15 @@ class EngineBackend(Backend):
         out = token.eng.collect_batch(token.step)
         latency = time.monotonic() - token.t0
         tokens: Dict[str, int] = {}
-        for m, slot in token.sampled:
-            if slot in out:
-                tok = sample(out[slot])
-                self.records[m.mr.parent.rid].generated.append(tok)
-                tokens[m.rid] = tok
+        with spans.span("backend.sample") as sp:
+            if sp is not None:
+                sp.set_metadata(seq=token.step.seq if token.step else -1,
+                                rows=len(token.sampled))
+            for m, slot in token.sampled:
+                if slot in out:
+                    tok = sample(out[slot])
+                    self.records[m.mr.parent.rid].generated.append(tok)
+                    tokens[m.rid] = tok
         return ExecResult(latency=latency, tokens=tokens, deferred=False)
 
     def execute(self, inst: InstanceState,

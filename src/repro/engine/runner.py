@@ -31,6 +31,7 @@ from repro.models.model import (
     forward, init_cache, init_paged_cache, supports_paged_kv,
 )
 from repro.models.tp import tp_context
+from repro.utils import spans
 from repro.utils.sharding import tp_cache_specs, tp_param_specs
 
 DEFAULT_MAX_CHUNK = 512
@@ -78,9 +79,16 @@ class StepHandle:
     results."""
     items: Sequence[BatchItem]
     logits: object              # device array, possibly still computing
+    seq: int = -1               # the engine's step number (its spans')
 
     def ready(self) -> bool:
         return self.logits.is_ready()
+
+
+def _nbytes(tree) -> int:
+    """Array bytes in a handoff piece (or a list of them)."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree)
+               if isinstance(x, np.ndarray))
 
 
 class InstanceEngine:
@@ -183,9 +191,8 @@ class InstanceEngine:
         self.free_slots = list(range(n_slots))
         self.slot_owner: Dict[int, str] = {}
         self._step_fns: Dict[tuple, callable] = {}
-        # counters for tests/benchmarks
+        # steps run; the next step's number in its profiler spans
         self.iterations = 0
-        self.tokens_processed = 0
         self.prefix_hit_tokens = 0
 
     # ---------------- tensor/expert parallelism ----------------
@@ -410,42 +417,57 @@ class InstanceEngine:
         empty batch."""
         if not items:
             return None
-        T = bucket_of(max(len(it.tokens) for it in items), self.buckets)
-        B = self.n_slots
-        tokens = np.zeros((B, T), np.int32)
-        pos_off = np.zeros((B,), np.int32)
-        n_valid = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        for it in items:
-            t = len(it.tokens)
-            tokens[it.slot, :t] = it.tokens
-            pos_off[it.slot] = it.pos_offset
-            n_valid[it.slot] = t
-            active[it.slot] = True
-        args = ()
-        n_pp = 0
-        if self.paged:
-            # grow block tables to cover every item's span before the
-            # write; OutOfPages here means the scheduler overcommitted.
-            # Growing may copy-on-write-fork shared prefix pages the
-            # write region touches — apply the KV copies first.
-            forks: List[Tuple[int, int]] = []
+        with spans.span("engine.dispatch") as sp:
+            longest = max(len(it.tokens) for it in items)
+            T = bucket_of(longest, self.buckets)
+            B = self.n_slots
+            tokens = np.zeros((B, T), np.int32)
+            pos_off = np.zeros((B,), np.int32)
+            n_valid = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
             for it in items:
-                forks.extend(self.allocator.ensure(
-                    it.slot, it.pos_offset + len(it.tokens)))
-            if forks:
-                self._apply_forks(forks)
-            n_pp = bucket_of(max(1, self.allocator.max_table_len),
-                             self.page_buckets)
-            args = (jnp.asarray(self.allocator.table_array(n_pp)),)
-        step = self._step_fn(T, n_pp)
-        logits, self.cache = step(self.params, self.cache,
-                                  jnp.asarray(tokens), jnp.asarray(pos_off),
-                                  jnp.asarray(n_valid), jnp.asarray(active),
-                                  *args)
-        self.iterations += 1
-        self.tokens_processed += int(sum(len(it.tokens) for it in items))
-        return StepHandle(items=items, logits=logits)
+                t = len(it.tokens)
+                tokens[it.slot, :t] = it.tokens
+                pos_off[it.slot] = it.pos_offset
+                n_valid[it.slot] = t
+                active[it.slot] = True
+            args = ()
+            n_pp = 0
+            if self.paged:
+                # grow block tables to cover every item's span before the
+                # write; OutOfPages here means the scheduler overcommitted.
+                # Growing may copy-on-write-fork shared prefix pages the
+                # write region touches — apply the KV copies first.
+                with spans.span("engine.tables") as tsp:
+                    forks: List[Tuple[int, int]] = []
+                    for it in items:
+                        forks.extend(self.allocator.ensure(
+                            it.slot, it.pos_offset + len(it.tokens)))
+                    if forks:
+                        self._apply_forks(forks)
+                    n_pp = bucket_of(max(1, self.allocator.max_table_len),
+                                     self.page_buckets)
+                    args = (jnp.asarray(self.allocator.table_array(n_pp)),)
+                    if tsp is not None:
+                        tsp.set_metadata(pages=sum(
+                            len(self.allocator.pages_of(it.slot))
+                            for it in items))
+            seq = self.iterations
+            with spans.span("engine.launch") as lsp:
+                if lsp is not None:
+                    lsp.set_metadata(
+                        new_program=int((T, n_pp) not in self._step_fns))
+                step = self._step_fn(T, n_pp)
+                logits, self.cache = step(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(pos_off), jnp.asarray(n_valid),
+                    jnp.asarray(active), *args)
+            self.iterations += 1
+            if sp is not None:
+                sp.set_metadata(seq=seq, T=T, n_pp=n_pp, slots=B,
+                                rows=len(items), tokens=int(n_valid.sum()),
+                                decode=int(longest == 1))
+        return StepHandle(items=items, logits=logits, seq=seq)
 
     def collect_batch(self, handle: Optional[StepHandle]) \
             -> Dict[int, np.ndarray]:
@@ -453,7 +475,13 @@ class InstanceEngine:
         logits} for its want_logits items."""
         if handle is None:
             return {}
-        logits = np.asarray(handle.logits)
+        with spans.span("engine.collect") as sp:
+            if sp is not None:
+                sp.set_metadata(seq=handle.seq)
+            with spans.span("engine.wait"):
+                handle.logits.block_until_ready()
+            with spans.span("engine.fetch"):
+                logits = np.asarray(handle.logits)
         return {it.slot: logits[it.slot]
                 for it in handle.items if it.want_logits}
 
@@ -511,7 +539,6 @@ class InstanceEngine:
             active=jnp.asarray(active), n_valid=jnp.asarray(n_valid),
             last_only=True, window_override=self.window_override, **kw)
         self.iterations += 1
-        self.tokens_processed += total
         return np.asarray(logits[slot, 0])
 
     # ---------------- micro-request state handoff ----------------
@@ -533,6 +560,14 @@ class InstanceEngine:
         if start:
             raise ValueError("prefix-skipping export requires a paged "
                              "cache")
+        with spans.span("engine.export") as sp:
+            pieces = self._export_dense(slot, upto, chunk)
+            if sp is not None:
+                sp.set_metadata(rid=self.slot_owner.get(slot, ""),
+                                bytes=_nbytes(pieces))
+        return pieces
+
+    def _export_dense(self, slot: int, upto: int, chunk: int) -> List[dict]:
         cfg = self.cfg
         pieces: List[dict] = []
         spans = ([(0, upto)] if not chunk else
@@ -622,22 +657,27 @@ class InstanceEngine:
             piece = {"span": (p0 * page, min(p1 * page, upto)),
                      "page_size": page, "pages": [],
                      "precision": self.kv_precision.name}
-            for i in range(len(self.cfg.layer_pattern)):
-                c = self.cache["blocks"][i]
-                # the wire format is token-major (G, n, page, KV, hd) +
-                # (G, n, page) scales, whatever the pool's own layout
-                pc = {}
-                for kv in ("k", "v"):
-                    scales = c.get(f"{kv}_scales")
-                    codes, sc = from_pool_layout(
-                        np.asarray(c[f"{kv}_pages"][:, ids]),
-                        None if scales is None else np.asarray(scales[:, ids]))
-                    pc[kv] = codes
-                    if sc is not None:
-                        # quantized pool: the per-token dequant scales
-                        # ride with their code pages
-                        pc[f"{kv}_scales"] = sc
-                piece["pages"].append(pc)
+            with spans.span("engine.export") as sp:
+                for i in range(len(self.cfg.layer_pattern)):
+                    c = self.cache["blocks"][i]
+                    # the wire format is token-major (G, n, page, KV, hd)
+                    # + (G, n, page) scales, whatever the pool's layout
+                    pc = {}
+                    for kv in ("k", "v"):
+                        scales = c.get(f"{kv}_scales")
+                        codes, sc = from_pool_layout(
+                            np.asarray(c[f"{kv}_pages"][:, ids]),
+                            None if scales is None
+                            else np.asarray(scales[:, ids]))
+                        pc[kv] = codes
+                        if sc is not None:
+                            # quantized pool: the per-token dequant
+                            # scales ride with their code pages
+                            pc[f"{kv}_scales"] = sc
+                    piece["pages"].append(pc)
+                if sp is not None:
+                    sp.set_metadata(rid=self.slot_owner.get(slot, ""),
+                                    bytes=_nbytes(piece))
             yield piece
             if p1 >= n_need:
                 break
@@ -719,6 +759,13 @@ class InstanceEngine:
         self.cache = dict(self.cache, blocks=tuple(blocks))
 
     def import_state(self, slot: int, pieces: Sequence[dict]) -> None:
+        with spans.span("engine.import") as sp:
+            if sp is not None:
+                sp.set_metadata(rid=self.slot_owner.get(slot, ""),
+                                bytes=_nbytes(pieces))
+            self._import(slot, pieces)
+
+    def _import(self, slot: int, pieces: Sequence[dict]) -> None:
         if self.paged:
             if any("blocks" in p for p in pieces):
                 raise ValueError("dense-cache pieces cannot be imported "
