@@ -212,15 +212,20 @@ def idle_line(win: Window) -> str:
 
 
 def queue_wait_ms(ctx) -> Optional[float]:
-    """Median wait from a request's due time to its first batch, over the
-    requests due in the window that started (``session.start``'s
-    ``wait_us``)."""
+    """Median wait from a request's due time to its first batch
+    (``session.start``'s ``wait_us``), over every request due in the
+    window; as for TTFT, one that has not started by the close (shed,
+    or still queued) enters at the close less its due time."""
     win = window(ctx)
     if win is None:
         return None
     w = ctx.w
-    due = {a.rid: a.due for a in w.arrivals}
-    waits = [s.stats["wait_us"] * 1e-3
-             for s in win.named("session.start", False)
-             if w.open_t <= due.get(s.stats["rid"], -1.0) < w.close_t]
-    return statistics.median(waits) if waits else None
+    due = {a.rid: a.due for a in w.arrivals if w.open_t <= a.due < w.close_t}
+    if not due:
+        return None
+    waits = {rid: w.close_t - d for rid, d in due.items()}
+    for s in win.named("session.start", False):
+        rid = s.stats["rid"]
+        if rid in waits:
+            waits[rid] = min(waits[rid], s.stats["wait_us"] * 1e-6)
+    return 1e3 * statistics.median(waits.values())

@@ -801,9 +801,7 @@ class ServeSession:
         wall = self._wall()
         if self.cfg.open_loop and t > wall:
             # idle by design: nothing is due before t
-            with spans.span("session.sleep") as sp:
-                if sp is not None:
-                    sp.set_metadata(us=int((t - wall) * 1e6))
+            with spans.span("session.sleep"):
                 _time.sleep(t - wall)
             wall = self._wall()
         self.now = max(self.now, wall)
@@ -1334,10 +1332,8 @@ class ServeSession:
         with spans.span("session.arrival") as sp:
             self._arrive(r)
             if sp is not None:
-                sp.set_metadata(
-                    rid=r.rid, now_us=int(now * 1e6),
-                    late_us=int((now - r.arrival) * 1e6),
-                    admitted=int(r.state != RequestState.REJECTED))
+                sp.set_metadata(rid=r.rid, now_us=int(now * 1e6),
+                                late_us=int((now - r.arrival) * 1e6))
 
     def _arrive(self, r: Request) -> None:
         """Admission verdict, global-scheduler split and placement."""
@@ -1599,9 +1595,7 @@ class ServeSession:
             h = self._next_batch(inst)
             if sp is not None:
                 sp.set_metadata(seq=self.backend.step_seq(inst.iid),
-                                now_us=int(self.now * 1e6),
-                                prefill_rows=len(h.grants) if h else 0,
-                                decode_rows=len(h.decs) if h else 0)
+                                now_us=int(self.now * 1e6))
         if h is None:
             return False
         inst.inflight.append(h)

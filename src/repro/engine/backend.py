@@ -502,8 +502,7 @@ class EngineBackend(Backend):
         tokens: Dict[str, int] = {}
         with spans.span("backend.sample") as sp:
             if sp is not None:
-                sp.set_metadata(seq=token.step.seq if token.step else -1,
-                                rows=len(token.sampled))
+                sp.set_metadata(seq=token.step.seq if token.step else -1)
             for m, slot in token.sampled:
                 if slot in out:
                     tok = sample(out[slot])

@@ -418,8 +418,7 @@ class InstanceEngine:
         if not items:
             return None
         with spans.span("engine.dispatch") as sp:
-            longest = max(len(it.tokens) for it in items)
-            T = bucket_of(longest, self.buckets)
+            T = bucket_of(max(len(it.tokens) for it in items), self.buckets)
             B = self.n_slots
             tokens = np.zeros((B, T), np.int32)
             pos_off = np.zeros((B,), np.int32)
@@ -438,7 +437,7 @@ class InstanceEngine:
                 # write; OutOfPages here means the scheduler overcommitted.
                 # Growing may copy-on-write-fork shared prefix pages the
                 # write region touches — apply the KV copies first.
-                with spans.span("engine.tables") as tsp:
+                with spans.span("engine.tables"):
                     forks: List[Tuple[int, int]] = []
                     for it in items:
                         forks.extend(self.allocator.ensure(
@@ -448,10 +447,6 @@ class InstanceEngine:
                     n_pp = bucket_of(max(1, self.allocator.max_table_len),
                                      self.page_buckets)
                     args = (jnp.asarray(self.allocator.table_array(n_pp)),)
-                    if tsp is not None:
-                        tsp.set_metadata(pages=sum(
-                            len(self.allocator.pages_of(it.slot))
-                            for it in items))
             seq = self.iterations
             with spans.span("engine.launch") as lsp:
                 if lsp is not None:
@@ -464,9 +459,8 @@ class InstanceEngine:
                     jnp.asarray(active), *args)
             self.iterations += 1
             if sp is not None:
-                sp.set_metadata(seq=seq, T=T, n_pp=n_pp, slots=B,
-                                rows=len(items), tokens=int(n_valid.sum()),
-                                decode=int(longest == 1))
+                sp.set_metadata(seq=seq, T=T, slots=B,
+                                tokens=int(n_valid.sum()))
         return StepHandle(items=items, logits=logits, seq=seq)
 
     def collect_batch(self, handle: Optional[StepHandle]) \
@@ -477,9 +471,10 @@ class InstanceEngine:
             return {}
         with spans.span("engine.collect") as sp:
             if sp is not None:
+                # traced only: split the wait for the step from the copy
                 sp.set_metadata(seq=handle.seq)
-            with spans.span("engine.wait"):
-                handle.logits.block_until_ready()
+                with spans.span("engine.wait"):
+                    handle.logits.block_until_ready()
             with spans.span("engine.fetch"):
                 logits = np.asarray(handle.logits)
         return {it.slot: logits[it.slot]

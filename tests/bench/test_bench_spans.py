@@ -85,7 +85,6 @@ def test_engine_steps_in_a_real_trace(smoke_model, tmp_path):
         assert _inside(wt, c) and _inside(f, c) and c.start >= d.end
         assert c.stats["seq"] == d.stats["seq"]
         assert l.stats["new_program"] in (0, 1)
-        assert t.stats["pages"] > 0
     assert [d.stats["seq"] for d in disp] == [1, 2]
     # the harness's annotation around each dispatch, in the same order
     marks = sorted((e for e in bench_trace.load(str(tmp_path))
@@ -95,12 +94,11 @@ def test_engine_steps_in_a_real_trace(smoke_model, tmp_path):
         assert e.start_ns * 1e-9 <= d.start and \
             d.end <= (e.start_ns + e.duration_ns) * 1e-9
     first, second = (d.stats for d in disp)
-    assert first == {"seq": 1, "T": bucket_of(9, eng.buckets), "n_pp":
-                     first["n_pp"], "slots": 4, "rows": 2, "tokens": 15,
-                     "decode": 0}
-    assert second == {"seq": 2, "T": 1, "n_pp": second["n_pp"], "slots": 4,
-                      "rows": 2, "tokens": 2, "decode": 1}
-    assert first["n_pp"] >= 2 and [s.T for s in steps] == [9, 1]
+    assert first == {"seq": 1, "T": bucket_of(9, eng.buckets), "slots": 4,
+                     "tokens": 15}
+    assert second == {"seq": 2, "T": 1, "slots": 4, "tokens": 2}
+    assert [s.T for s in steps] == [9, 1]
+    assert [sum(n for n, _, _ in s.spans) for s in steps] == [15, 2]
 
 
 def test_served_session_in_a_real_trace(smoke_model, tmp_path):
@@ -131,7 +129,7 @@ def test_served_session_in_a_real_trace(smoke_model, tmp_path):
     assert all(s.stats["wait_us"] >= 0 and s.end == pytest.approx(
         s.start, abs=1e-3) for s in starts)
     arrivals = [s for s in got if s.name == "session.arrival"]
-    assert [s.stats["admitted"] for s in arrivals] == [1, 1, 1]
+    assert [s.stats["rid"] for s in arrivals] == ["r0", "r1", "r2"]
     assert all(s.stats["late_us"] >= 0 for s in arrivals)
     for name in ("engine.export", "engine.import"):
         assert all(s.stats["bytes"] > 0 and s.stats["rid"]
@@ -146,6 +144,38 @@ def test_served_session_in_a_real_trace(smoke_model, tmp_path):
     win = spans.place(W(), spans.load(str(tmp_path)))
     disp = [s for s in got if s.name == "engine.dispatch"]
     assert win.named("engine.dispatch") == disp
+
+
+def test_collect_waits_apart_only_while_tracing(smoke_model, tmp_path):
+    """Untraced, ``collect_batch`` copies the logits and nothing more;
+    traced, it waits for the step under its own span first."""
+    cfg, params = smoke_model
+    eng = InstanceEngine(cfg, params, n_slots=4, max_len=64)
+    a = eng.alloc("a")
+    waits = []
+
+    class Logits:
+        def __init__(self, x):
+            self.x = np.asarray(x)
+
+        def block_until_ready(self):
+            waits.append(1)
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            return self.x
+
+    prompt = np.arange(1, 5, dtype=np.int32)
+    h = eng.dispatch_batch([BatchItem(a, prompt[:3], 0, True)])
+    h.logits = Logits(h.logits)
+    assert set(eng.collect_batch(h)) == {a} and waits == []
+    h = eng.dispatch_batch([BatchItem(a, prompt[3:], 3, True)])
+    h.logits = Logits(h.logits)
+    with _traced(tmp_path):
+        assert set(eng.collect_batch(h)) == {a}
+    assert waits == [1]
+    names = [s.name for s in spans.load(str(tmp_path)).spans]
+    assert names == ["engine.collect", "engine.wait", "engine.fetch"]
 
 
 def test_no_span_stats_without_a_trace(smoke_model, monkeypatch):
@@ -184,39 +214,38 @@ def ev(name, start_ms, dur_ms, line="python", **stats):
                  tuple(stats.items()))
 
 
-def step(seq, t, T, tokens, rows, work_ms, slots=4):
+def step(seq, t, T, tokens, work_ms, slots=4):
     """A step's host spans from t (ms): build, dispatch (tables, launch),
     then collect (wait until the device is done, fetch), sample; the
     device runs from the launch's end for work_ms."""
     dev0 = t + 2.0
     return [
         ev("backend.build", t - 1.0, 1.0, seq=seq),
-        ev("engine.dispatch", t, 2.0, seq=seq, T=T, n_pp=4, slots=slots,
-           rows=rows, tokens=tokens, decode=int(T == 1)),
-        ev("engine.tables", t + 0.2, 0.8, pages=8),
+        ev("engine.dispatch", t, 2.0, seq=seq, T=T, slots=slots,
+           tokens=tokens),
+        ev("engine.tables", t + 0.2, 0.8),
         ev("engine.launch", t + 1.0, 1.0, new_program=0),
         ev("engine.collect", t + 2.0, work_ms + 1.0, seq=seq),
         ev("engine.wait", t + 2.0, work_ms),
         ev("engine.fetch", t + 2.0 + work_ms, 1.0),
-        ev("backend.sample", t + 3.0 + work_ms, 0.5, seq=seq, rows=rows),
+        ev("backend.sample", t + 3.0 + work_ms, 0.5, seq=seq),
         ev("%fusion.1 = f(%x)", dev0, work_ms, line="XLA Ops"),
     ]
 
 
 def synthetic():
     # session clock 0 is trace 500 s; the window is session 100-200 ms
-    evs = [ev("session.compose", 98.0, 1.0, seq=7, now_us=98_000,
-              prefill_rows=1, decode_rows=1),
+    evs = [ev("session.compose", 98.0, 1.0, seq=7, now_us=98_000),
            ev("session.arrival", 99.0, 0.5, rid="r1", now_us=99_000,
-              late_us=4_000, admitted=1),
+              late_us=4_000),
            ev("session.start", 99.2, 0.0, rid="r1", seq=7, wait_us=4_200),
            ev("session.start", 99.3, 0.0, rid="r0", seq=7, wait_us=90_000)]
-    evs += step(7, 101.0, 512, 513, 2, 20.0)       # launch ends 103
+    evs += step(7, 101.0, 512, 513, 20.0)       # launch ends 103
     # collect 103-124; the host is idle under no span 124.5-130
     evs += [ev("session.batch_done", 124.5 + 5.5, 2.0, seq=7,
                now_us=130_000)]
-    evs += step(8, 133.0, 1, 3, 3, 10.0)           # launch ends 135
-    evs += step(9, 150.0, 1, 3, 3, 10.0)           # launch ends 152
+    evs += step(8, 133.0, 1, 3, 10.0)           # launch ends 135
+    evs += step(9, 150.0, 1, 3, 10.0)           # launch ends 152
     evs += [ev("session.start", 160.0, 0.0, rid="r2", seq=10,
                wait_us=12_000)]
     return evs
@@ -231,15 +260,15 @@ class W:
                 for r, d in (("r0", 0.05), ("r1", 0.105), ("r2", 0.150))]
 
 
-def _ctx():
+def _ctx(w=None):
     spans._CACHE[W.trace_dir] = spans.from_events(synthetic())
-    return Context(w=W(), tr=object(), peak={}, chips=1)
+    return Context(w=w or W(), tr=object(), peak={}, chips=1)
 
 
-def _read(name):
+def _read(name, w=None):
     sys.path.insert(0, str(bench_smoke.ROOT / "bench"))
     import run
-    return run._reader(name)(_ctx())
+    return run._reader(name)(_ctx(w))
 
 
 def test_window_placed_by_the_session_clock():
@@ -265,10 +294,47 @@ def test_step_pad_pct_counts_padded_positions():
         100 * (1 - 519 / 2056))
 
 
-@pytest.mark.parametrize("name", ["queue_wait_ms", "queue_wait_ms.chat"])
-def test_queue_wait_ms_over_requests_due_in_the_window(name):
+def test_queue_wait_ms_over_requests_due_in_the_window():
     # r0 was due before the window and is left out
-    assert _read(name) == pytest.approx(8.1)
+    assert _read("queue_wait_ms") == pytest.approx(8.1)
+
+
+def test_queue_wait_ms_counts_a_request_not_started_at_the_close():
+    # r3, due at 190 ms, has no session.start: it enters at the close
+    # (10 ms), so a longer queue cannot lower the reading by leaving its
+    # slowest requests out
+    class Late(W):
+        arrivals = W.arrivals + [type("A", (), {"rid": "r3",
+                                                "due": 0.190})()]
+    assert _read("queue_wait_ms", Late()) == pytest.approx(10.0)
+
+    # a start recorded after the close counts at the close too
+    class Early(W):
+        close_t = 0.155
+    assert _read("queue_wait_ms", Early()) == pytest.approx(
+        (4.2 + 5.0) / 2)
+
+
+def test_queue_wait_ms_is_absent_with_no_request_due_in_the_window():
+    class Quiet(W):
+        arrivals = W.arrivals[:1]           # r0, due before the window
+    assert _read("queue_wait_ms", Quiet()) is None
+
+
+def test_readers_find_nothing_in_a_program_without_spans(capsys):
+    """A traced run of a program that writes no spans (an older commit):
+    each reader returns nothing, raises nothing and prints no idle
+    line."""
+    class Bare(W):
+        trace_dir = "synthetic-no-spans"
+    spans._CACHE[Bare.trace_dir] = spans.from_events(
+        [e for e in synthetic() if e.line == "XLA Ops"])
+    sys.path.insert(0, str(bench_smoke.ROOT / "bench"))
+    import run
+    ctx = Context(w=Bare(), tr=object(), peak={}, chips=1)
+    for name in ("host_gap_ms", "step_pad_pct", "queue_wait_ms"):
+        assert run._reader(name)(ctx) is None
+    assert "idle_by_program_span" not in capsys.readouterr().out
 
 
 def test_idle_put_down_to_innermost_span():
