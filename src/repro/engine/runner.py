@@ -437,7 +437,7 @@ class InstanceEngine:
                 # write; OutOfPages here means the scheduler overcommitted.
                 # Growing may copy-on-write-fork shared prefix pages the
                 # write region touches — apply the KV copies first.
-                with spans.span("engine.tables"):
+                with spans.span("engine.tables") as tsp:
                     forks: List[Tuple[int, int]] = []
                     for it in items:
                         forks.extend(self.allocator.ensure(
@@ -447,6 +447,12 @@ class InstanceEngine:
                     n_pp = bucket_of(max(1, self.allocator.max_table_len),
                                      self.page_buckets)
                     args = (jnp.asarray(self.allocator.table_array(n_pp)),)
+                    if tsp is not None and T == 1:
+                        # the decode kernel streams each row's own pages
+                        # of the n_pp its table holds
+                        tsp.set_metadata(n_pp=n_pp, pages=sum(
+                            pages_for(self.allocator.len_of(it.slot),
+                                      self.page_size) for it in items))
             seq = self.iterations
             with spans.span("engine.launch") as lsp:
                 if lsp is not None:

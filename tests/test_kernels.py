@@ -104,7 +104,7 @@ def test_paged_decode_vs_ref(dtype, B, H, KV, hd, page, ppseq):
 
 
 @pytest.mark.parametrize("page", [8, 16, 32])
-@pytest.mark.parametrize("qpk", [1, 2, 4, 8])
+@pytest.mark.parametrize("qpk", [1, 2, 3, 4, 5, 8])
 def test_paged_decode_gqa_and_page_size_sweep(qpk, page):
     """Parity across GQA group sizes x page sizes with ragged lengths
     (every sequence at a different, non-page-aligned context)."""
@@ -122,6 +122,49 @@ def test_paged_decode_gqa_and_page_size_sweep(qpk, page):
     exp = paged_decode_attention_ref(q, kp, vp, tbl, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("page,n_pp,lens", [
+    # the kernel streams 256-token blocks: 32 pages of 8, 8 of 32
+    (8, 40, [256, 257, 255, 320]),      # at a block multiple, one past, short
+    (32, 10, [256, 257, 255, 320]),
+    (8, 40, [1, 7, 100, 200]),          # rows shorter than one block
+    (8, 3, [1, 9, 24, 17]),             # a table narrower than a block
+    (8, 40, [1, 320, 1, 320]),          # 1-token rows beside full tables
+    (32, 10, [320, 1]),
+])
+def test_paged_decode_block_edges(page, n_pp, lens):
+    """Parity where the kernel's blocks begin and end: each row streams
+    only its own pages, block by block, and masks the tail of its last
+    block."""
+    B, KV, qpk, hd = len(lens), 2, 3, 64
+    n_pages = B * n_pp + 1
+    q = _rand((B, KV * qpk, hd), jnp.float32)
+    kp = _pool((n_pages, page, KV, hd), jnp.float32)
+    vp = _pool((n_pages, page, KV, hd), jnp.float32)
+    tbl = jnp.asarray(
+        RNG.permutation(n_pages)[:B * n_pp].reshape(B, n_pp), jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    out = paged_decode_attention_op(q, kp, vp, tbl, lens, interpret=True)
+    exp = paged_decode_attention_ref(q, kp, vp, tbl, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_empty_row_is_zero():
+    """A row of length 0 streams no page and returns zeros, after a row
+    whose blocks left the buffers full, and the row after it is exact."""
+    B, KV, qpk, hd, page, n_pp = 3, 2, 3, 64, 8, 40
+    q = _rand((B, KV * qpk, hd), jnp.float32)
+    kp = _pool((B * n_pp, page, KV, hd), jnp.float32)
+    vp = _pool((B * n_pp, page, KV, hd), jnp.float32)
+    tbl = jnp.arange(B * n_pp, dtype=jnp.int32).reshape(B, n_pp)
+    lens = jnp.asarray([300, 0, 77], jnp.int32)
+    out = np.asarray(paged_decode_attention_op(q, kp, vp, tbl, lens,
+                                               interpret=True))
+    exp = np.asarray(paged_decode_attention_ref(q, kp, vp, tbl, lens))
+    np.testing.assert_array_equal(out[1], 0.0)
+    np.testing.assert_allclose(out[[0, 2]], exp[[0, 2]], rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("page,Tq,ctx", [
@@ -183,18 +226,33 @@ def test_chunked_prefill_per_row_ragged_offsets():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_ignores_pages_beyond_length():
+# (table width, length): the length's last page and the pages after it
+# fall inside the last 32-page block the kernel streams
+POISON = [(4, 11), (40, 267)]
+
+
+def _poison(pool, length, page, value):
+    """``pool`` (identity table) with every token at or past ``length``
+    set to ``value``: the tail of the length's last page and all later
+    pages."""
+    last, used = divmod(length, page)
+    pool = pool.at[last + 1:].set(value)
+    return pool.at[last, :, used:].set(value)
+
+
+@pytest.mark.parametrize("ppseq,length", POISON)
+def test_paged_decode_ignores_pages_beyond_length(ppseq, length):
     """Garbage in pages past ``length`` must not leak into the output."""
-    B, H, KV, hd, page, ppseq = 1, 4, 2, 32, 8, 4
-    n_pages = 8
+    B, H, KV, hd, page = 1, 4, 2, 32, 8
+    n_pages = ppseq + 4
     q = _rand((B, H, hd), jnp.float32)
     kp = _pool((n_pages, page, KV, hd), jnp.float32)
     vp = _pool((n_pages, page, KV, hd), jnp.float32)
     tbl = jnp.arange(ppseq, dtype=jnp.int32)[None]
-    lens = jnp.array([11], jnp.int32)
+    lens = jnp.array([length], jnp.int32)
     out1 = paged_decode_attention_op(q, kp, vp, tbl, lens, interpret=True)
-    kp2 = kp.at[2:].set(1e6)       # poison pages beyond length
-    vp2 = vp.at[2:].set(-1e6)
+    kp2 = _poison(kp, length, page, 1e6)     # poison tokens past length
+    vp2 = _poison(vp, length, page, -1e6)
     out2 = paged_decode_attention_op(q, kp2, vp2, tbl, lens, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-6)
@@ -240,11 +298,12 @@ def test_quantize_roundtrip_error_bound(prec):
 
 
 @pytest.mark.parametrize("prec", QPREC)
-@pytest.mark.parametrize("page,qpk", [(8, 1), (16, 2), (32, 4)])
-def test_paged_decode_quantized_parity(prec, page, qpk):
+@pytest.mark.parametrize("page,qpk,ppseq", [(8, 1, 3), (16, 2, 3),
+                                            (32, 4, 3), (8, 3, 36)])
+def test_paged_decode_quantized_parity(prec, page, qpk, ppseq):
     """GQA sizes x page sizes x ragged lengths through the quantized
-    decode kernel."""
-    B, KV, hd, ppseq = 3, 2, 64, 3
+    decode kernel, within one block and across two."""
+    B, KV, hd = 3, 2, 64
     H = KV * qpk
     n_pages = B * ppseq + 1
     q = _rand((B, H, hd), jnp.float32)
@@ -312,23 +371,25 @@ def test_paged_prefill_quantized_gathers_scales(prec):
 
 
 @pytest.mark.parametrize("prec", QPREC)
-def test_paged_decode_quantized_ignores_poison_pages(prec):
+@pytest.mark.parametrize("ppseq,length", POISON)
+def test_paged_decode_quantized_ignores_poison_pages(prec, ppseq, length):
     """Garbage codes AND garbage scales in pages past ``length`` must not
     leak into the quantized decode output."""
-    B, H, KV, hd, page, ppseq = 1, 4, 2, 32, 8, 4
-    n_pages = 8
+    B, H, KV, hd, page = 1, 4, 2, 32, 8
+    n_pages = ppseq + 4
     q = _rand((B, H, hd), jnp.float32)
     _, kc, ks, _ = _qpool((n_pages, page, KV, hd), prec)
     _, vc, vs, _ = _qpool((n_pages, page, KV, hd), prec)
     tbl = jnp.arange(ppseq, dtype=jnp.int32)[None]
-    lens = jnp.array([11], jnp.int32)
+    lens = jnp.array([length], jnp.int32)
     out1 = paged_decode_attention_op(q, kc, vc, tbl, lens, ks, vs,
                                      interpret=True)
     qmax = 127 if prec == "int8" else 448
-    kc2 = kc.at[2:].set(jnp.asarray(qmax, kc.dtype))   # poison codes
-    vc2 = vc.at[2:].set(jnp.asarray(-qmax, vc.dtype))
-    ks2 = ks.at[2:].set(1e6)                            # poison scales
-    vs2 = vs.at[2:].set(1e6)
+    # poison codes and scales
+    kc2 = _poison(kc, length, page, jnp.asarray(qmax, kc.dtype))
+    vc2 = _poison(vc, length, page, jnp.asarray(-qmax, vc.dtype))
+    ks2 = _poison(ks, length, page, 1e6)
+    vs2 = _poison(vs, length, page, 1e6)
     out2 = paged_decode_attention_op(q, kc2, vc2, tbl, lens, ks2, vs2,
                                      interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),

@@ -4,7 +4,8 @@ Interpret mode (every other kernel test) never checks Mosaic's tiling
 rules, so these compile the paged-decode, chunked-prefill and
 paged-prefill kernels for a *described* v5e:2x2 — no chip attached —
 at Qwen2.5-14B head shapes (H=40, KV=8, hd=128) and at the per-shard
-widths of a TP=4 instance (H=10, KV=2).  The compiler refuses a bad
+widths of a TP=4 instance (H=10, KV=2), and the paged-decode kernel at
+Phi-4-mini's (H=24, KV=8) over a full serving table.  The compiler refuses a bad
 block shape here exactly as it would on the chip.
 
 The topology is described inside a module fixture (never at import):
@@ -67,6 +68,17 @@ def test_paged_decode_compiles(v5e, width, prec, page):
     pages, scales = _pool_shapes(KV, page, prec)
     assert _compiles_to_kernel(
         paged_decode_attention_op, v5e, ((B, H, HD), jnp.bfloat16),
+        *pages, ((B, n_pp), jnp.int32), ((B,), jnp.int32), *scales)
+
+
+@pytest.mark.parametrize("prec", ["bf16", "int8"])
+def test_paged_decode_compiles_at_a_full_table(v5e, prec):
+    """Phi-4-mini's widths (24 heads over 8 KV heads) on 16 rows of an
+    8-token-page table 512 pages wide, as the serving engine runs them."""
+    B, n_pp, page = 16, 512, 8
+    pages, scales = _pool_shapes(8, page, prec, n_pages=2560)
+    assert _compiles_to_kernel(
+        paged_decode_attention_op, v5e, ((B, 24, HD), jnp.bfloat16),
         *pages, ((B, n_pp), jnp.int32), ((B,), jnp.int32), *scales)
 
 
