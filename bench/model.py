@@ -2,9 +2,11 @@
 reads it: the published model sizes, the cut, and the deployment.
 
 The file keeps the source's own key names (a Hugging Face ``config.json``)
-so that it can be compared with the source line by line.  This module
-turns it into the sizes the weight generator, the plain reference and
-the work functions use, and into the program's ``ModelConfig``.
+so that it can be compared with the source line by line, and names its
+model family (``"family"``, a module of ``bench/families/``).  The family
+turns the file into the sizes the weight generator, the plain reference
+and the work functions use, and into the program's ``ModelConfig``; this
+module holds what every family shares.
 """
 from __future__ import annotations
 
@@ -12,24 +14,22 @@ import dataclasses
 import json
 import pathlib
 
+from bench import families
+
 CONFIG_DIR = pathlib.Path(__file__).resolve().parent / "configs"
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class ModelSpec:
+    """The fields every family's spec holds; a family's spec is a frozen
+    subclass that adds its own sizes, so jit can take it as static."""
+    family: str
     name: str
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
     vocab: int
+    d_model: int
     n_layers: int
-    qkv_bias: bool
-    tied: bool
-    rope_theta: float
-    rope_fraction: float
     norm_eps: float
+    tied: bool
     # deployment
     n_slots: int
     max_context: int
@@ -43,23 +43,18 @@ class ModelSpec:
         return self.kv_pool_tokens // self.page_size
 
 
-def spec_from_dict(c: dict) -> ModelSpec:
+def shared_fields(c: dict) -> dict:
+    """``ModelSpec``'s fields from a configuration file that uses the
+    Hugging Face key names, for a family's ``spec``."""
     dep = c["deployment"]
-    heads = c["num_attention_heads"]
-    return ModelSpec(
+    return dict(
+        family=c["family"],
         name=c["name"],
-        d_model=c["hidden_size"],
-        n_heads=heads,
-        n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim", c["hidden_size"] // heads),
-        d_ff=c["intermediate_size"],
         vocab=c["vocab_size"],
+        d_model=c["hidden_size"],
         n_layers=c["num_hidden_layers"],
-        qkv_bias=c["qkv_bias"],
-        tied=c["tie_word_embeddings"],
-        rope_theta=float(c["rope_theta"]),
-        rope_fraction=float(c.get("partial_rotary_factor", 1.0)),
         norm_eps=float(c["rms_norm_eps"]),
+        tied=c["tie_word_embeddings"],
         n_slots=dep["n_slots"],
         max_context=dep["max_context"],
         kv_pool_tokens=dep["kv_pool_tokens"],
@@ -72,22 +67,10 @@ def load_spec(name: str) -> ModelSpec:
     path = CONFIG_DIR / f"{name}.json"
     if not path.is_file():
         raise SystemExit(f"bench: no configuration file {path}")
-    return spec_from_dict(json.loads(path.read_text()))
+    c = json.loads(path.read_text())
+    return families.load(c.get("family"), path).spec(c)
 
 
 def program_config(spec: ModelSpec):
-    """The program's ``ModelConfig`` for this configuration.  The
-    program's RMSNorm has a fixed epsilon and no key for it, so a file
-    that states another one cannot be run as stated."""
-    from repro.models.config import ModelConfig
-    if spec.norm_eps != 1e-6:
-        raise SystemExit(f"bench: {spec.name} states rms_norm_eps "
-                         f"{spec.norm_eps}; the program's RMSNorm uses 1e-6")
-    return ModelConfig(
-        name=spec.name, arch_type="dense", n_layers=spec.n_layers,
-        d_model=spec.d_model, n_heads=spec.n_heads,
-        n_kv_heads=spec.n_kv_heads, d_ff=spec.d_ff, vocab_size=spec.vocab,
-        head_dim=spec.head_dim, mlp="swiglu", norm="rmsnorm",
-        qkv_bias=spec.qkv_bias, tie_embeddings=spec.tied,
-        rope_theta=spec.rope_theta, rope_fraction=spec.rope_fraction,
-        dtype="bfloat16")
+    """The program's ``ModelConfig`` for this configuration."""
+    return families.of(spec).program_config(spec)

@@ -1,26 +1,18 @@
-"""Plain reference: the decoder's forward pass in float32 ``jax.numpy``.
+"""Plain reference: the model's forward pass in float32 ``jax.numpy``.
 
 No kernels, no cache, no batching of requests: one sequence at a time,
 layer by layer, with each layer's weights regenerated from the seed
 (``bench.weights.layer_weights``) and widened to float32, and every
-matrix product at ``precision="highest"``.  Attention is computed in
-blocks of query rows so that a 16k-token sequence fits beside nothing
-else on the chip.
+matrix product at ``precision="highest"``.
 
-The layer equations (one dense GQA decoder layer, pre-norm):
-
-    h  = x + Wo · attn(rope(Wq·n1(x) + bq), rope(Wk·n1(x) + bk), Wv·n1(x) + bv)
-    x' = h + W2 · (silu(Wg·n2(h)) * (Wi·n2(h)))
-    n(x) = x / sqrt(mean(x²) + eps) * scale
-
-with causal softmax attention scaled by 1/sqrt(head_dim), query head h
-reading KV head h // (heads / kv_heads), and logits = n_f(x) · Wout.
-
-One departure from the published models, shared with the program: RoPE
-rotates adjacent pairs of the first ``rope_fraction * head_dim`` dims
-(GPT-J pairing), where the Hugging Face checkpoints rotate the two
-halves.  The two are the same model under a fixed permutation of the
-q/k projection columns, which random weights do not distinguish.
+The layers' equations are the family's (``bench/families/<family>.py``,
+``layer``), which writes them with the shared pieces here: ``_mm``,
+``_norm`` (RMSNorm), ``_rope`` and ``causal_attention``, which computes
+attention in blocks of query rows so that a 16k-token sequence fits
+beside nothing else on the chip.  Shared too: the embedding lookup
+before the first layer, and after the last the final norm and the
+logits, ``n_f(x) · Wout`` with ``Wout`` the embedding's transpose when
+tied, taken over the vocabulary in blocks (``logit_gaps``).
 
 ``quant="fp8"`` rounds both operands of every matrix product to
 float8_e4m3fn first: the lower-precision control of the check.
@@ -34,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import families
 from bench.model import ModelSpec
 from bench.weights import layer_weights, top_weights
 
@@ -52,12 +45,12 @@ def _norm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, s: ModelSpec):
-    """x (L, heads, hd): rotate adjacent pairs of the first rot dims."""
-    rot = int(s.head_dim * s.rope_fraction)
+def _rope(x, pos, theta, fraction):
+    """x (L, heads, hd): rotate adjacent pairs of the first
+    ``fraction * hd`` dims by position ``pos`` (L,) at base ``theta``."""
+    rot = int(x.shape[-1] * fraction)
     rot -= rot % 2
-    freqs = 1.0 / (s.rope_theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
-                                    / rot))
+    freqs = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
     ang = pos.astype(jnp.float32)[:, None] * freqs          # (L, rot/2)
     sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
     x1, x2 = x[..., 0:rot:2], x[..., 1:rot:2]
@@ -66,24 +59,15 @@ def _rope(x, pos, s: ModelSpec):
                            -1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _layer(s: ModelSpec, x, w, quant):
-    """One decoder layer over a whole (padded) sequence x (L, d)."""
-    L = x.shape[0]
-    H, KV, hd = s.n_heads, s.n_kv_heads, s.head_dim
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
-    pos = jnp.arange(L)
-    h = _norm(x, w["norm1/scale"], s.norm_eps)
-    q, k, v = (_mm(h, w["mixer/wq"], quant), _mm(h, w["mixer/wk"], quant),
-               _mm(h, w["mixer/wv"], quant))
-    if s.qkv_bias:
-        q, k, v = q + w["mixer/bq"], k + w["mixer/bk"], v + w["mixer/bv"]
-    q = _rope(q.reshape(L, H, hd), pos, s)
-    k = _rope(k.reshape(L, KV, hd), pos, s)
-    v = v.reshape(L, KV, hd)
-    g = H // KV
+def causal_attention(q, k, v, quant):
+    """Causal softmax attention of q (L, H, hd) over k, v (L, KV, hd),
+    scaled by 1/sqrt(hd), query head h reading KV head h // (H / KV);
+    L a multiple of ``Q_BLOCK``.  Returns (L, H * hd)."""
+    L, H, hd = q.shape
+    g = H // k.shape[1]
     kh = jnp.repeat(k, g, axis=1).transpose(1, 0, 2)       # (H, L, hd)
     vh = jnp.repeat(v, g, axis=1).transpose(1, 0, 2)
+    pos = jnp.arange(L)
     n_blk = L // Q_BLOCK
 
     def block(i):
@@ -96,11 +80,7 @@ def _layer(s: ModelSpec, x, w, quant):
         p = jax.nn.softmax(sc, axis=-1)
         return _mm(p, vh, quant).transpose(1, 0, 2)        # (Qb, H, hd)
 
-    att = jax.lax.map(block, jnp.arange(n_blk)).reshape(L, H * hd)
-    x = x + _mm(att, w["mixer/wo"], quant)
-    h = _norm(x, w["norm2/scale"], s.norm_eps)
-    ff = jax.nn.silu(_mm(h, w["mlp/wg"], quant)) * _mm(h, w["mlp/wi"], quant)
-    return x + _mm(ff, w["mlp/wo"], quant)
+    return jax.lax.map(block, jnp.arange(n_blk)).reshape(L, H * hd)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
@@ -147,9 +127,10 @@ def final_hidden(s: ModelSpec, seed: int, prompts: Sequence[np.ndarray],
         ids[:len(t)] = t
         xs.append(top["embed"][jnp.asarray(ids)].astype(jnp.float32))
     del top
+    fam = families.of(s)
     for layer in range(s.n_layers):
         w = layer_weights(s, seed, layer)
-        xs = [_layer(s, x, w, quant) for x in xs]
+        xs = [fam.layer(s, x, w, layer, quant) for x in xs]
         del w
     return [x[len(p) - 1:len(p) - 1 + len(o)]
             for x, p, o in zip(xs, prompts, served)]

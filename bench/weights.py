@@ -1,15 +1,20 @@
 """Random weights from ``--seed``, made on the device in one jitted call.
 
-Every leaf has its own key, ``fold_in(seed key, leaf number)``, and every
-layer of a stacked leaf ``fold_in(leaf key, layer)``.  So the plain
-reference regenerates one layer at a time (``layer_weights``) and gets
-the very values the served program holds, without taking anything the
-program made.
+The leaves, their shapes and inits come from the configuration's family
+(``top_leaves``, ``layer_leaves``).  Every top leaf has its own key,
+``fold_in(seed key, i)``; leaf ``j`` of layer ``l``'s own list has
+``fold_in(fold_in(seed key, n_top + j), l)``.  So the plain reference
+regenerates one layer at a time (``layer_weights``) and gets the very
+values the served program holds, without taking anything the program
+made.
 
-The tree is laid out as the program's ``init_params`` lays it out (a
-scan over layers: block leaves stacked on a leading layer axis).
-``check_layout`` compares the two before a run, so a change of the
-program's layout stops the run instead of feeding it a wrong tree.
+The tree is laid out as the program's ``init_params`` lays it out:
+``blocks`` a tuple over the positions of the layer pattern (period P),
+layer ``l`` at position ``l % P`` of a leading group axis, and the
+``n_layers % P`` layers past the last whole period unstacked in
+``tail``.  ``check_layout`` compares the two before a run, so a change
+of the program's layout stops the run instead of feeding it a wrong
+tree.
 """
 from __future__ import annotations
 
@@ -18,39 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench import families
 from bench.model import ModelSpec
-
-def _layer_leaves(s: ModelSpec):
-    """(name, shape, init) of one decoder layer's leaves; init is
-    ("normal", std) or ("ones_jitter", std) for norm scales.  Biases and
-    norm scales are drawn, not zeros and ones, so the reference checks
-    them too."""
-    d, hd = s.d_model, s.head_dim
-    qd, kd = s.n_heads * hd, s.n_kv_heads * hd
-    out = [
-        ("norm1/scale", (d,), ("ones_jitter", 0.1)),
-        ("mixer/wq", (d, qd), ("normal", d ** -0.5)),
-        ("mixer/wk", (d, kd), ("normal", d ** -0.5)),
-        ("mixer/wv", (d, kd), ("normal", d ** -0.5)),
-        ("mixer/wo", (qd, d), ("normal", qd ** -0.5)),
-        ("norm2/scale", (d,), ("ones_jitter", 0.1)),
-        ("mlp/wi", (d, s.d_ff), ("normal", d ** -0.5)),
-        ("mlp/wg", (d, s.d_ff), ("normal", d ** -0.5)),
-        ("mlp/wo", (s.d_ff, d), ("normal", s.d_ff ** -0.5)),
-    ]
-    if s.qkv_bias:
-        out += [("mixer/bq", (qd,), ("normal", 0.1)),
-                ("mixer/bk", (kd,), ("normal", 0.1)),
-                ("mixer/bv", (kd,), ("normal", 0.1))]
-    return out
-
-
-def _top_leaves(s: ModelSpec):
-    out = [("embed", (s.vocab, s.d_model), ("normal", 0.02)),
-           ("final_norm/scale", (s.d_model,), ("ones_jitter", 0.1))]
-    if not s.tied:
-        out.append(("lm_head", (s.d_model, s.vocab), ("normal", 0.02)))
-    return out
 
 
 def seed_key(seed: int):
@@ -75,27 +49,46 @@ def _leaf_key(key, i):
     return jax.random.fold_in(key, i)
 
 
-def _put(tree, path, value):
-    *head, last = path.split("/")
-    for h in head:
-        tree = tree.setdefault(h, {})
-    tree[last] = value
+def _nest(flat):
+    """``{"mixer/wq": v, ...}`` as the program's nested dicts."""
+    tree = {}
+    for path, value in flat.items():
+        *head, last = path.split("/")
+        node = tree
+        for h in head:
+            node = node.setdefault(h, {})
+        node[last] = value
+    return tree
+
+
+def _layer_draw(leaves, n_top, key, layer):
+    return {name: _draw(jax.random.fold_in(_leaf_key(key, n_top + j), layer),
+                        shape, init)
+            for j, (name, shape, init) in enumerate(leaves)}
 
 
 @functools.partial(jax.jit, static_argnums=0)
 def _make(s: ModelSpec, key):
-    tree = {}
-    top = _top_leaves(s)
-    for i, (name, shape, init) in enumerate(top):
-        _put(tree, name, _draw(_leaf_key(key, i), shape, init))
-    block = {}
-    layers = jnp.arange(s.n_layers)
-    for j, (name, shape, init) in enumerate(_layer_leaves(s)):
-        lk = _leaf_key(key, len(top) + j)
-        draw = lambda l, lk=lk, shape=shape, init=init: _draw(
-            jax.random.fold_in(lk, l), shape, init)
-        _put(block, name, jax.vmap(draw)(layers))
-    tree["blocks"] = (block,)
+    fam = families.of(s)
+    period = len(fam.program_config(s).layer_pattern)
+    whole = s.n_layers - s.n_layers % period
+    top = fam.top_leaves(s)
+    tree = _nest({name: _draw(_leaf_key(key, i), shape, init)
+                  for i, (name, shape, init) in enumerate(top)})
+    blocks = []
+    for p in range(period):
+        leaves = fam.layer_leaves(s, p)
+        if any(fam.layer_leaves(s, l) != leaves
+               for l in range(p, whole, period)):
+            raise ValueError(f"{s.name}: the layers at pattern position "
+                             f"{p} do not share one leaf list")
+        draw = lambda l, leaves=leaves: _layer_draw(leaves, len(top), key, l)
+        blocks.append(_nest(jax.vmap(draw)(jnp.arange(p, whole, period))))
+    tree["blocks"] = tuple(blocks)
+    if whole < s.n_layers:
+        tree["tail"] = tuple(
+            _nest(_layer_draw(fam.layer_leaves(s, l), len(top), key, l))
+            for l in range(whole, s.n_layers))
     return tree
 
 
@@ -104,23 +97,23 @@ def make_params(s: ModelSpec, seed: int):
     return _make(s, seed_key(seed))
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _layer(s: ModelSpec, key, layer):
-    n_top = len(_top_leaves(s))
-    return {name: _draw(jax.random.fold_in(_leaf_key(key, n_top + j), layer),
-                        shape, init)
-            for j, (name, shape, init) in enumerate(_layer_leaves(s))}
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer(leaves, n_top, key, layer):
+    return _layer_draw(leaves, n_top, key, layer)
 
 
 def layer_weights(s: ModelSpec, seed: int, layer: int):
     """Layer ``layer``'s leaves by name (``"mixer/wq"``, ...), bf16."""
-    return _layer(s, seed_key(seed), layer)
+    fam = families.of(s)
+    return _layer(tuple(fam.layer_leaves(s, layer)), len(fam.top_leaves(s)),
+                  seed_key(seed), layer)
 
 
 @functools.partial(jax.jit, static_argnums=0)
 def _top(s: ModelSpec, key):
     return {name: _draw(_leaf_key(key, i), shape, init)
-            for i, (name, shape, init) in enumerate(_top_leaves(s))}
+            for i, (name, shape, init)
+            in enumerate(families.of(s).top_leaves(s))}
 
 
 def top_weights(s: ModelSpec, seed: int):

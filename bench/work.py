@@ -5,13 +5,17 @@ peak then reads the same whatever implements the step.
 A span is ``(n, start, sampled)``: ``n`` tokens at positions
 ``start .. start + n - 1`` of one sequence, ``sampled`` when the step
 turns its last position into a token.  FLOPs count a multiply-add as 2.
-Bytes are bf16 (2 bytes a value).
+Bytes are bf16 (2 bytes a value).  The counts of the layers and the head
+are the configuration's family's (``bench/families/<family>.py``), summed
+over its own layers: an expert layer counts its active experts, a
+windowed layer the keys in its window.
 """
 from __future__ import annotations
 
 import json
 import pathlib
 
+from bench import families
 from bench.model import ModelSpec
 
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
@@ -25,38 +29,33 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def dense_flops_per_token(s: ModelSpec) -> float:
-    """Projections and MLP of every layer (embedding lookup is free)."""
-    hd = s.head_dim
-    per_layer = (s.d_model * (s.n_heads + 2 * s.n_kv_heads) * hd
-                 + s.n_heads * hd * s.d_model + 3 * s.d_model * s.d_ff)
-    return 2.0 * per_layer * s.n_layers
+def matmul_flops_per_token(s: ModelSpec) -> float:
+    """Every layer's matrix products a token needs (the embedding lookup
+    is free, the head is counted apart)."""
+    return families.of(s).matmul_flops_per_token(s)
 
 
 def head_flops(s: ModelSpec) -> float:
-    return 2.0 * s.d_model * s.vocab
+    return families.of(s).head_flops(s)
 
 
 def attn_flops(s: ModelSpec, n: int, start: int) -> float:
-    """Causal attention of n queries at start.. over keys 0..pos, all
-    layers: QK^T and PV, 2 FLOPs each per (query, key, head dim)."""
-    keys = n * start + n * (n + 1) // 2
-    return 4.0 * s.n_heads * s.head_dim * keys * s.n_layers
+    """Attention of n queries at positions start.., all layers."""
+    return families.of(s).attn_flops(s, n, start)
 
 
 def attn_bytes(s: ModelSpec, n: int, start: int) -> float:
-    """K and V of the n + start visible positions read once, q read and
-    the output written for the n queries, all layers."""
-    kv = 2 * s.n_kv_heads * s.head_dim * (start + n)
-    qo = 2 * s.n_heads * s.head_dim * n
-    return 2.0 * (kv + qo) * s.n_layers
+    """Bytes the attention of n queries at start.. reads and writes, all
+    layers."""
+    return families.of(s).attn_bytes(s, n, start)
 
 
 def step_flops(s: ModelSpec, spans) -> float:
     """Useful FLOPs of one step: every span's tokens through the layers,
     their attention, and the head for each sampled span."""
-    return sum(dense_flops_per_token(s) * n + attn_flops(s, n, start)
-               + (head_flops(s) if sampled else 0.0)
+    fam = families.of(s)
+    return sum(fam.matmul_flops_per_token(s) * n + fam.attn_flops(s, n, start)
+               + (fam.head_flops(s) if sampled else 0.0)
                for n, start, sampled in spans)
 
 

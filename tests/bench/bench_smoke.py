@@ -7,13 +7,16 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench.model import ModelSpec  # noqa: E402
+from bench.families import dense_gqa  # noqa: E402
 
-SPEC = ModelSpec(name="smoke", d_model=256, n_heads=8, n_kv_heads=2,
-                 head_dim=32, d_ff=512, vocab=512, n_layers=2,
-                 qkv_bias=True, tied=False, rope_theta=1e6,
-                 rope_fraction=1.0, norm_eps=1e-6, n_slots=4,
-                 max_context=32, kv_pool_tokens=128)
+SPEC = dense_gqa.spec({
+    "family": "dense_gqa", "name": "smoke", "hidden_size": 256,
+    "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 32,
+    "intermediate_size": 512, "vocab_size": 512, "num_hidden_layers": 2,
+    "qkv_bias": True, "tie_word_embeddings": False, "rope_theta": 1e6,
+    "rms_norm_eps": 1e-6,
+    "deployment": {"n_slots": 4, "max_context": 32, "kv_pool_tokens": 128,
+                   "instances": 1, "chips": 1}})
 MIX = {"classes": [{"weight": 1.0, "prompt": {"median": 10, "sigma": 0.3},
                     "output": {"median": 6, "sigma": 0.3}}],
        "prompt_clip": [4, 16], "output_clip": [3, 8], "rate_per_s": 4.0,
